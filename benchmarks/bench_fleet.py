@@ -52,6 +52,7 @@ import numpy as np
 import jax
 
 from benchmarks.common import RESULTS_DIR, csv_row, emit
+from repro.compile_cache import use_compile_cache
 from repro.fleet import (
     FleetParams, SweepConfig, fleet_run, make_fleet, make_workload,
     run_sweep,
@@ -372,6 +373,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="one-invocation million-replica sharded sweep; "
                          "merges the `mega` row into BENCH_fleet.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.mesh_smoke:
         return run_mesh_smoke()
     if args.mega:

@@ -18,6 +18,8 @@ import os
 import time
 from typing import Callable
 
+from repro.compile_cache import use_compile_cache
+
 
 @dataclasses.dataclass(frozen=True)
 class BenchSpec:
@@ -126,6 +128,7 @@ def main() -> None:
     if args.list:
         list_benches()
         return
+    use_compile_cache()
     selected = REGISTRY
     if args.only:
         known = {b.name for b in REGISTRY}

@@ -11,6 +11,7 @@ bands in results/calib/baseline.json (the same gate CI enforces).
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.calib import CalibConfig, check_report, load_baseline, run_calibration
 from repro.calib.harness import DELTA_KEYS, PAPER_TRACES
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--congestion", type=float, default=0.0,
                     help="§VI.C burst duty-cycle for both engines")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = CalibConfig(scenarios=PAPER_TRACES,
                       congestion_levels=(args.congestion,),

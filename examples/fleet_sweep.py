@@ -11,6 +11,7 @@ Fig.-4-style completion table with 95% confidence intervals.
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.fleet import SweepConfig, run_sweep
 
 
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--seeds", type=int, default=64,
                     help="replicas per congestion level")
     args = ap.parse_args()
+    use_compile_cache()
 
     levels = (0.0, 0.2, 0.4, 0.6)
     cfg = SweepConfig(

@@ -10,11 +10,14 @@
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, reduced
 from repro.core.scheduler import RASScheduler
 from repro.core.tasks import LPRequest, Priority, Task
 from repro.launch.train import train
 from repro.models.transformer import Model
+
+use_compile_cache()
 
 # -- 1. deadline-constrained scheduling ------------------------------------
 print("== 1. RAS scheduler ==")

@@ -11,6 +11,7 @@ import argparse
 import dataclasses
 import json
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.launch.train import train
 import repro.launch.train as T
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = hundred_m()
     n = cfg.param_count()

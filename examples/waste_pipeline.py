@@ -12,6 +12,7 @@ Both schedulers are run on the same trace for comparison.
 import argparse
 import json
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.serve import serve
 
 
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--frames", type=int, default=25)
     ap.add_argument("--trace", default="weighted3")
     args = ap.parse_args()
+    use_compile_cache()
 
     out = {}
     for sched in ("ras", "wps"):

@@ -108,9 +108,34 @@ def _csum(x):
     return jnp.sum(jnp.where(tril, x[..., :, None], 0), axis=-2)
 
 
+def _iota(shape, axis):
+    """int32 iota along ``axis`` of ``shape`` (never the default int, which
+    is int64 under JAX_ENABLE_X64)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _tree_sum(x, axis):
+    """Sum over ``axis`` (kept as size 1) by pairwise halving, in a fixed
+    order.  A reduce leaves the float summation order to the backend;
+    spelling the adds out fixes it, so XLA and Mosaic round the same sums
+    identically."""
+    tails = []
+    while x.shape[axis] > 1:
+        n = x.shape[axis]
+        h = n // 2
+        if n % 2:
+            tails.append(jax.lax.slice_in_dim(x, n - 1, n, axis=axis))
+        x = (jax.lax.slice_in_dim(x, 0, h, axis=axis)
+             + jax.lax.slice_in_dim(x, h, 2 * h, axis=axis))
+    for t in tails:
+        x = x + t
+    return x
+
+
 def _trim_tracks(t1, t2, valid, s, e, md, active):
     """Multi-remainder trim of ``[s, e)`` from every window of the active
-    tracks (``[..., W]`` arrays; ``s``/``e``/``md``/``active`` broadcast).
+    tracks (``[..., W, N]`` arrays, replica axis last; ``s``/``e``/``md``/
+    ``active`` broadcast).
 
     Every overlapping window keeps its left piece ``[t1, s)`` and right
     piece ``[e, t2)`` when they satisfy the minimum duration — the exact
@@ -126,10 +151,10 @@ def _trim_tracks(t1, t2, valid, s, e, md, active):
     Pieces that satisfy the minimum duration but find no free slot (or
     extra straddles of non-disjoint test inputs) are *counted*, never
     silently lost: returns ``(t1', t2', valid', n_dropped, time_dropped)``
-    with the drop tallies reduced over the window axis.
+    with the drop tallies reduced over the window axis (kept as size 1).
     """
-    W = t1.shape[-1]
-    lanes = jnp.arange(W, dtype=jnp.int32)
+    W = t1.shape[-2]
+    slots = _iota((W, 1), 0)
     ov = valid & (t1 < e) & (s < t2) & active
     left_t2 = jnp.minimum(t2, s)
     right_t1 = jnp.maximum(t1, e)
@@ -138,7 +163,7 @@ def _trim_tracks(t1, t2, valid, s, e, md, active):
     both = left_ok & right_ok
     # in-place: the slot keeps the left piece when it survives, else the
     # right piece, else goes free
-    new_valid = jnp.where(ov, left_ok | right_ok, valid)
+    new_valid = (ov & (left_ok | right_ok)) | (~ov & valid)
     new_t1 = jnp.where(ov & ~left_ok & right_ok, right_t1, t1)
     new_t2 = jnp.where(ov & left_ok, left_t2, t2)
     new_t1 = jnp.where(new_valid, new_t1, BIG)
@@ -146,27 +171,72 @@ def _trim_tracks(t1, t2, valid, s, e, md, active):
     # spill the (single) straddle's right piece into the first free slot
     # — first-index min-reduces, no argmin/gather
     first_free = jnp.min(
-        jnp.where(~new_valid, lanes, W), axis=-1, keepdims=True
+        jnp.where(~new_valid, slots, W), axis=-2, keepdims=True
     )
-    first_both = jnp.min(jnp.where(both, lanes, W), axis=-1, keepdims=True)
+    first_both = jnp.min(jnp.where(both, slots, W), axis=-2, keepdims=True)
     placed = (first_both < W) & (first_free < W)
-    oh_b = both & (lanes == first_both)
-    sp_t1 = jnp.sum(jnp.where(oh_b, right_t1, 0.0), axis=-1, keepdims=True)
-    sp_t2 = jnp.sum(jnp.where(oh_b, t2, 0.0), axis=-1, keepdims=True)
-    place = placed & (lanes == first_free)
+    oh_b = both & (slots == first_both)
+    sp_t1 = jnp.sum(jnp.where(oh_b, right_t1, 0.0), axis=-2, keepdims=True)
+    sp_t2 = jnp.sum(jnp.where(oh_b, t2, 0.0), axis=-2, keepdims=True)
+    place = placed & (slots == first_free)
     new_t1 = jnp.where(place, sp_t1, new_t1)
     new_t2 = jnp.where(place, sp_t2, new_t2)
     new_valid = new_valid | place
     # every straddle right piece except a successfully-placed first one
     # is dropped (counted, not lost)
-    dropped = both & ~(placed & (lanes == first_both))
-    n_drop = dropped.sum(axis=-1).astype(jnp.int32)
-    t_drop = jnp.where(dropped, t2 - right_t1, 0.0).sum(axis=-1)
+    dropped = both & ~(placed & (slots == first_both))
+    n_drop = jnp.sum(dropped.astype(jnp.int32), axis=-2, keepdims=True,
+                     dtype=jnp.int32)
+    t_drop = jnp.sum(jnp.where(dropped, t2 - right_t1, 0.0), axis=-2,
+                     keepdims=True)
     return new_t1, new_t2, new_valid, n_drop, t_drop
 
 
+def _commit_row(t1d, t2d, vd, md, cfg, s, e, do):
+    """§IV.A.1 fan-out trim of one device's windows, replica axis last:
+    consume ``[s, e)`` from the ``OCC_TABLE[cfg, ci]`` most-overlapping
+    tracks of every config list ``ci`` (multi-remainder).
+
+    ``t1d``/``t2d``/``vd`` are ``[CFG, T, W, N]``; ``md`` ``[CFG, 1, N]``;
+    ``cfg`` i32, ``s``/``e`` f32 and ``do`` bool are ``[1, N]``.  Returns
+    ``(t1', t2', valid', n_dropped [1, N], time_dropped [1, N])``; the
+    rows are meaningful only where ``do`` holds (the callers write back
+    under that mask).  Both commit forms below share this one trace.
+    """
+    n_cfg, T = t1d.shape[:2]
+    ov = vd & (t1d < e) & (s < t2d)
+    ol = _tree_sum(
+        jnp.where(ov, jnp.minimum(t2d, e) - jnp.maximum(t1d, s), 0.0), axis=2
+    )                                                          # [CFG, T, 1, N]
+    # stable descending rank of tracks by overlap (first index wins ties):
+    # beats[c, t, u] = track u outranks track t
+    ol_t, ol_u = ol[:, :, None], ol[:, None]
+    u_first = _iota((T, T, 1, 1), 1) < _iota((T, T, 1, 1), 0)
+    beats = (ol_u > ol_t) | ((ol_u == ol_t) & u_first)
+    rank = jnp.sum(beats.astype(jnp.int32), axis=2, dtype=jnp.int32)
+    # occupancy width: ceil(task_cores / track_cores), selected from
+    # OCC_TABLE by the (data-dependent) committed config.  Unrolled over
+    # the tiny static table with scalar constants only, so no array
+    # constant is captured when this traces inside the Pallas kernel.
+    list_ids = _iota((n_cfg, 1, 1), 0)
+    occ = jnp.zeros((n_cfg,) + cfg.shape, jnp.int32)
+    for ti in range(n_cfg):
+        for li in range(n_cfg):
+            occ = jnp.where(
+                (cfg == ti) & (list_ids == li),
+                jnp.int32(OCC_TABLE[ti, li]), occ,
+            )                                                  # [CFG, 1, N]
+    active = do & (rank < occ[:, None]) & (ol > 0.0)           # [CFG, T, 1, N]
+    nt1, nt2, nv, n_drop, t_drop = _trim_tracks(
+        t1d, t2d, vd, s, e, md[:, None], active
+    )
+    n_drop = jnp.where(do, jnp.sum(n_drop, axis=(0, 1), dtype=jnp.int32), 0)
+    t_drop = jnp.where(do, jnp.sum(t_drop, axis=(0, 1)), 0.0)
+    return nt1, nt2, nv, n_drop, t_drop
+
+
 def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
-                  kernel_safe: bool = False, sanitize: bool = False):
+                  sanitize: bool = False):
     """Batched §IV.A.1 fan-out commit: consume ``[s, e)`` on device
     ``dev`` across every config list, trimming the ``OCC_TABLE[cfg, ci]``
     most-overlapping tracks of each list ``ci`` (multi-remainder).
@@ -176,85 +246,33 @@ def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
     ``[N]`` masks the commit per row.  Returns
     ``(t1', t2', valid', n_dropped [N], time_dropped [N])``.
 
-    ``kernel_safe`` picks the device gather/scatter lowering; the trim
-    math in between is identical either way, so both forms produce
-    bit-identical values:
-
-    - ``False`` (default, the fleet-scan hot path): ``take_along_axis``
-      gather + ``.at[] .set`` scatter — XLA updates the committed device
-      row in place inside a scan instead of rewriting the whole
-      ``[N, Dev, CFG, T, W]`` state per commit.  ~25 commits/tick make
-      full-array rewrites the dominant engine cost.
-    - ``True``: broadcast/compare/reduce only (one-hot where + sum), the
-      subset that lowers inside the Pallas placement kernel body.
+    The committed device row is gathered with ``take_along_axis`` and
+    written back with an ``.at[].set`` scatter, so XLA updates that row
+    in place inside the fleet scan instead of rewriting the whole
+    ``[N, Dev, CFG, T, W]`` state per commit.  The trim itself is
+    ``_commit_row`` on the row turned replica-last, the same trace the
+    Pallas placement kernel commits with (``fanout_commit_lanes``).
     """
-    N, n_dev, n_cfg, T, W = t1.shape
-    dev_oh = (jnp.arange(n_dev, dtype=jnp.int32)[None, :]
-              == dev[:, None])                                 # [N, Dev]
-    if kernel_safe:
-        gather = lambda a, fill: jnp.sum(
-            jnp.where(dev_oh[:, :, None, None, None], a, fill), axis=1
-        )
-        t1d = gather(t1, 0.0)                                  # [N, CFG, T, W]
-        t2d = gather(t2, 0.0)
-        vd = jnp.any(valid & dev_oh[:, :, None, None, None], axis=1)
-    else:
-        idx = dev[:, None, None, None, None]
-        take = lambda a: jnp.take_along_axis(a, idx, axis=1)[:, 0]
-        t1d = take(t1)                                         # [N, CFG, T, W]
-        t2d = take(t2)
-        vd = take(valid)
-    sb = s[:, None, None, None]
-    eb = e[:, None, None, None]
-    ov = vd & (t1d < eb) & (sb < t2d)
-    ol = jnp.where(ov, jnp.minimum(t2d, eb) - jnp.maximum(t1d, sb), 0.0)
-    ol = ol.sum(axis=-1)                                       # [N, CFG, T]
-    # stable descending rank of tracks by overlap (first index wins ties)
-    track_ids = jnp.arange(T, dtype=jnp.int32)
-    beats = (ol[..., None, :] > ol[..., :, None]) | (
-        (ol[..., None, :] == ol[..., :, None])
-        & (track_ids[None, :] < track_ids[:, None])
-    )
-    rank = beats.sum(axis=-1)                                  # [N, CFG, T]
-    # occupancy width: ceil(task_cores / track_cores), selected from
-    # OCC_TABLE by the (data-dependent) committed config.  Unrolled over
-    # the tiny static table with scalar constants only, so no array
-    # constant is captured when this traces inside the Pallas kernel.
-    list_ids = jnp.arange(n_cfg, dtype=jnp.int32)[None, :]
-    occ = jnp.zeros((N, n_cfg), jnp.int32)
-    for ti in range(n_cfg):
-        for li in range(n_cfg):
-            occ = jnp.where(
-                (cfg[:, None] == ti) & (list_ids == li),
-                jnp.int32(OCC_TABLE[ti, li]), occ,
-            )                                                  # [N, CFG]
-    active = (
-        do[:, None, None] & (rank < occ[:, :, None]) & (ol > 0.0)
-    )                                                          # [N, CFG, T]
-    md = min_dur[:, :, None, None]
-    nt1, nt2, nv, n_drop, t_drop = _trim_tracks(
-        t1d, t2d, vd, sb, eb, md, active[..., None]
+    N = t1.shape[0]
+    idx = dev[:, None, None, None, None]
+    take = lambda a: jnp.take_along_axis(a, idx, axis=1)[:, 0]
+    t1d, t2d, vd = take(t1), take(t2), take(valid)             # [N, CFG, T, W]
+    lanes = lambda a: jnp.moveaxis(a, 0, -1)
+    back = lambda a: jnp.moveaxis(a, -1, 0)
+    nt1, nt2, nv, n_drop, t_drop = _commit_row(
+        lanes(t1d), lanes(t2d), lanes(vd), min_dur.T[:, None], cfg[None],
+        s[None], e[None], do[None],
     )
     # write back only committed rows (do=False rows stay bit-identical)
-    if kernel_safe:
-        sel = (dev_oh & do[:, None])[:, :, None, None, None]
-        out_t1 = jnp.where(sel, nt1[:, None], t1)
-        out_t2 = jnp.where(sel, nt2[:, None], t2)
-        out_valid = jnp.where(sel, nv[:, None], valid)
-    else:
-        rows = jnp.arange(N, dtype=jnp.int32)
-        dom = do[:, None, None, None]
-        out_t1 = t1.at[rows, dev].set(jnp.where(dom, nt1, t1d))
-        out_t2 = t2.at[rows, dev].set(jnp.where(dom, nt2, t2d))
-        out_valid = valid.at[rows, dev].set(jnp.where(dom, nv, vd))
-    # explicit accumulator dtype: integer jnp.sum promotes to the default
-    # int (int64 under JAX_ENABLE_X64), which does not lower on TPU
-    n_drop = jnp.where(do, n_drop.sum(axis=(1, 2), dtype=jnp.int32), 0)
-    t_drop = jnp.where(do, t_drop.sum(axis=(1, 2)), 0.0)
+    rows = jnp.arange(N, dtype=jnp.int32)
+    dom = do[:, None, None, None]
+    out_t1 = t1.at[rows, dev].set(jnp.where(dom, back(nt1), t1d))
+    out_t2 = t2.at[rows, dev].set(jnp.where(dom, back(nt2), t2d))
+    out_valid = valid.at[rows, dev].set(jnp.where(dom, back(nv), vd))
+    n_drop, t_drop = n_drop[0], t_drop[0]
     if sanitize:
         # checkify invariants (only valid under a checkify.checkify
-        # transform, and never with kernel_safe=True — checks cannot
-        # lower inside a Pallas kernel body)
+        # transform; checks cannot lower inside a Pallas kernel body)
         _sanitize.check_windows(out_t1, out_t2, out_valid, "fanout_commit")
         _sanitize.check_no_avail_increase(
             _sanitize.total_availability(t1, t2, valid, batch_axes=1),
@@ -264,6 +282,28 @@ def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
             "fanout_commit",
         )
     return out_t1, out_t2, out_valid, n_drop, t_drop
+
+
+def fanout_commit_lanes(t1, t2, valid, min_dur, dev, cfg, s, e, do):
+    """``fanout_commit`` in the Pallas placement kernel's layout, replica
+    axis last: windows ``[Dev, CFG, T, W, N]``; ``min_dur [CFG, 1, N]``;
+    ``dev``/``cfg`` i32, ``s``/``e`` f32, ``do`` bool ``[1, N]``.
+
+    Broadcast/compare/reduce ops only (one-hot ``where`` + sum over the
+    device axis instead of a gather/scatter), the subset that lowers in a
+    kernel body.  Returns ``(t1', t2', valid', n_dropped [1, N])``.
+    """
+    n_dev = t1.shape[0]
+    dev_oh = _iota((n_dev, 1, 1, 1, 1), 0) == dev              # [Dev,1,1,1,N]
+    t1d = jnp.sum(jnp.where(dev_oh, t1, 0.0), axis=0)          # [CFG, T, W, N]
+    t2d = jnp.sum(jnp.where(dev_oh, t2, 0.0), axis=0)
+    vd = jnp.max((valid & dev_oh).astype(jnp.int32), axis=0) > 0
+    nt1, nt2, nv, n_drop, _ = _commit_row(
+        t1d, t2d, vd, min_dur, cfg, s, e, do
+    )
+    sel = dev_oh & do
+    return (jnp.where(sel, nt1, t1), jnp.where(sel, nt2, t2),
+            (sel & nv) | (~sel & valid), n_drop)
 
 
 def compact_tracks(t1, t2, valid, *, eps: float = 1e-6):

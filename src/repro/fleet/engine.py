@@ -83,7 +83,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import checkify
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.analysis import sanitize as _sanitize
@@ -114,9 +113,10 @@ class FleetParams:
     stagger: float = 1.0
     #: fused_place_op backend: "auto" | "kernel" | "ref".
     placement_backend: str = "auto"
-    #: replica rows per fused-placement kernel tile (per shard when the
-    #: mesh is on; the kernel clamps to the local batch).
-    placement_block_b: int = 8
+    #: replicas per fused-placement kernel tile (per shard when the mesh
+    #: is on): a multiple of the 128 TPU lanes, or at least the local
+    #: batch, which is then one tile.
+    placement_block_b: int = 128
     #: shard the batch axis over this many devices of a 1-D `shard_map`
     #: mesh (fleet/mesh.py).  0 disables sharding entirely; 1 runs the
     #: sharded code path on a single-device mesh (useful for testing the
@@ -537,8 +537,8 @@ def _shard_segment(params: FleetParams, *, sanitize: bool):
                 P(None, _mesh.FLEET_AXIS), P(), P())
     out_specs = ((P(_mesh.FLEET_AXIS), P(None, _mesh.FLEET_AXIS))
                  if params.telemetry else P(_mesh.FLEET_AXIS))
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -559,6 +559,34 @@ def _run_segment_sharded_checked(params: FleetParams):
     return jax.jit(checkify.checkify(
         _shard_segment(params, sanitize=True), errors=checkify.user_checks
     ))
+
+
+def initial_carry(fleet: FleetState, pad_b: int = 0):
+    """The segment scan's carry for ``fleet``: its buffers and zero stats,
+    with ``pad_b`` padding replicas appended to the batch."""
+    B = fleet.sched.win_t1.shape[0]
+    Bp = B + pad_b
+    state_tree = (
+        fleet.sched, fleet.link_free,
+        (fleet.rq_deadline, fleet.rq_src, fleet.rq_valid),
+        (fleet.vc_start, fleet.vc_end, fleet.vc_deadline, fleet.vc_src,
+         fleet.vc_valid),
+    )
+    # copy the carry: the segment runners donate their input buffers, and
+    # the caller's fleet must stay valid (benchmarks re-run the same
+    # fleet).  The zero stats leaves are copied too — jnp.zeros dedupes
+    # identical constants, and donation rejects aliased buffers.  Batch
+    # padding tiles existing replica rows instead — any valid state
+    # works, the padded columns release no frames.
+    if pad_b:
+        rows = jnp.arange(Bp, dtype=jnp.int32) % B
+        state_tree = jax.tree_util.tree_map(
+            lambda x: jnp.take(x, rows, axis=0), state_tree
+        )
+    else:
+        state_tree = jax.tree_util.tree_map(jnp.copy, state_tree)
+    stats0 = jax.tree_util.tree_map(jnp.copy, init_stats(Bp))
+    return (*state_tree, stats0)
 
 
 def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
@@ -584,7 +612,6 @@ def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
     shards = p.mesh_shards
     sharded = shards >= 1
     pad_b = _mesh.shard_pad(B, shards) if sharded else 0
-    Bp = B + pad_b
     assert values.shape[2] == n_dev and fleet.sched.win_t1.shape[1] == n_dev
     assert fleet.rq_valid.shape == (B, R), (
         f"fleet re-queue buffer {fleet.rq_valid.shape} != (B={B}, "
@@ -623,31 +650,16 @@ def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
             [bw_scale, jnp.ones(bw_scale.shape[:1] + (pad_b,),
                                 jnp.float32)], axis=1,
         )
-    state_tree = (
-        fleet.sched, fleet.link_free,
-        (fleet.rq_deadline, fleet.rq_src, fleet.rq_valid),
-        (fleet.vc_start, fleet.vc_end, fleet.vc_deadline, fleet.vc_src,
-         fleet.vc_valid),
-    )
-    # copy the carry: the segment runners donate their input buffers, and
-    # the caller's fleet must stay valid (benchmarks re-run the same
-    # fleet).  The zero stats leaves are copied too — jnp.zeros dedupes
-    # identical constants, and donation rejects aliased buffers.  Batch
-    # padding tiles existing replica rows instead — any valid state
-    # works, the padded columns release no frames.
-    if pad_b:
-        rows = jnp.arange(Bp, dtype=jnp.int32) % B
-        state_tree = jax.tree_util.tree_map(
-            lambda x: jnp.take(x, rows, axis=0), state_tree
-        )
-    else:
-        state_tree = jax.tree_util.tree_map(jnp.copy, state_tree)
-    stats0 = jax.tree_util.tree_map(jnp.copy, init_stats(Bp))
-    carry = (*state_tree, stats0)
+    carry = initial_carry(fleet, pad_b)
     if sharded:
-        # commit the carry to the mesh once: the donated buffers then
-        # round-trip through every segment without a resharding copy
-        carry = _mesh.put_sharded(carry, _mesh.fleet_mesh(shards))
+        # commit the carry and the workload to the mesh once: the donated
+        # buffers then round-trip through every segment without a
+        # resharding copy, and each segment's workload slice is already
+        # split across the devices
+        mesh = _mesh.fleet_mesh(shards)
+        carry = _mesh.put_sharded(carry, mesh)
+        values, bw_scale = _mesh.put_sharded((values, bw_scale), mesh,
+                                             batch_axis=1)
     nf = jnp.asarray(F, jnp.int32)
     sanitized = _sanitize.enabled()
     telem_segs = []

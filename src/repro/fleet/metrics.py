@@ -27,6 +27,7 @@ failed / missed_by_preemption / still-pending-in-buffer, i.e.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -213,12 +214,15 @@ def cell_moments(stats: FleetStats, rq_valid, owner, *, n_cells: int,
     oh = (owner[:, None] == jnp.arange(n_cells, dtype=jnp.int32)[None, :]
           ).astype(jnp.float32)                            # [B, C]
     count = oh.sum(axis=0)
-    sums = oh.T @ mat                                      # [C, K]
+    # full f32 matmuls: the TPU's default precision rounds the operands
+    # to bf16, which moves a cell mean by ~1e-4 relative
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    sums = dot(oh.T, mat)                                  # [C, K]
     if axis_name is not None:
         count, sums = jax.lax.psum((count, sums), axis_name)
     mean = sums / jnp.maximum(count, 1.0)[:, None]
     centred = mat - mean[jnp.clip(owner, 0)]
-    m2 = oh.T @ (centred * centred)
+    m2 = dot(oh.T, centred * centred)
     resid_max = jnp.max(
         jnp.where(oh > 0, jnp.abs(resid)[:, None], 0), axis=0
     ).astype(jnp.int32)

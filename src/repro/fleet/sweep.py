@@ -27,7 +27,6 @@ from typing import Optional, Sequence
 import numpy as np
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.fleet import mesh as _mesh
@@ -159,12 +158,12 @@ def _cell_reducer(shards: int, n_cells: int, n_frames: int):
         axis_name=_mesh.FLEET_AXIS,
     )
     P = PartitionSpec
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn, mesh=_mesh.fleet_mesh(shards),
         in_specs=(P(_mesh.FLEET_AXIS), P(_mesh.FLEET_AXIS),
                   P(_mesh.FLEET_AXIS)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     # one-shot reduction over stats the caller still owns; the replicated
     # [C, K] output IS the intended O(metrics) transfer

@@ -8,35 +8,37 @@ aliased pair tiles identically (same block shape, index maps agreeing on
 every grid point) and that no *undeclared* pair shares a buffer — the
 exact edit that would silently corrupt fleet scheduler state.
 
-Shapes are post-padding: the wrapper pads B up to a multiple of
-``block_b`` with ``do=0`` replicas.
+Shapes are the kernel's replica-last operands after padding: the wrapper
+transposes the batch axis last and pads it up to a multiple of the tile
+with ``do=0`` replicas.
 """
 
 from __future__ import annotations
 
 from repro.analysis.pallas_check import BlockDecl, KernelGeometry, register
+from repro.kernels.placement.placement import block_size
 
 _MODULE = "repro.kernels.placement.placement"
 
 
 def _case(B, Dev, CFG, T, W, block_b):
-    block_b = min(block_b, B)
-    Bp = B + (-B) % block_b
-    n = Bp // block_b
+    bb = block_size(B, block_b)
+    Bp = B + (-B) % bb
+    n = Bp // bb
     win = lambda name, buf=None: BlockDecl(
-        name, (Bp, Dev, CFG, T, W), (block_b, Dev, CFG, T, W),
-        lambda i: (i, 0, 0, 0, 0), buffer=buf,
+        name, (Dev, CFG, T, W, Bp), (Dev, CFG, T, W, bb),
+        lambda i: (0, 0, 0, 0, i), buffer=buf,
     )
     devp = lambda name: BlockDecl(
-        name, (Bp, Dev), (block_b, Dev), lambda i: (i, 0)
+        name, (Dev, 1, Bp), (Dev, 1, bb), lambda i: (0, 0, i)
     )
     cfgp = lambda name: BlockDecl(
-        name, (Bp, CFG), (block_b, CFG), lambda i: (i, 0)
+        name, (CFG, 1, Bp), (CFG, 1, bb), lambda i: (0, 0, i)
     )
-    rep = lambda name: BlockDecl(name, (Bp,), (block_b,), lambda i: (i,))
+    rep = lambda name: BlockDecl(name, (1, Bp), (1, bb), lambda i: (0, i))
     return KernelGeometry(
         kernel="placement", module=_MODULE,
-        case=f"B{B}Dev{Dev}CFG{CFG}T{T}W{W}bb{block_b}",
+        case=f"B{B}Dev{Dev}CFG{CFG}T{T}W{W}bb{bb}",
         grid=(n,),
         inputs=(
             devp("q1"), devp("dl"), rep("src"), rep("do"), cfgp("min_dur"),
@@ -55,15 +57,17 @@ def _case(B, Dev, CFG, T, W, block_b):
 
 @register("placement")
 def geometries():
+    # paper testbed geometry (Dev=4, CFG=3, T=2, W=16) at the fleet
+    # engine's tile (block_b=128)
     return [
-        # paper testbed geometry at the fleet-engine tile (block_b=8)
-        _case(8, 4, 3, 2, 16, 8),
-        _case(1, 4, 3, 2, 16, 8),       # B=1 calib path
-        _case(20, 4, 3, 2, 16, 8),      # padded: 20 -> 24, three tiles
+        _case(8, 4, 3, 2, 16, 128),      # one tile of the whole batch
+        _case(1, 4, 3, 2, 16, 128),      # B=1 calib path
+        _case(300, 4, 3, 2, 16, 128),    # padded: 300 -> 384, three tiles
+        _case(4096, 4, 3, 2, 16, 128),   # sweep batch of 4096 on one chip
         # sharded fleet: each mesh shard launches over its local batch
-        # (global B / shards).  B=16 tests on 2/8 shards and sweep
-        # batches of 256/2048 on an 8-way mesh.
-        _case(2, 4, 3, 2, 16, 8),       # B=16 @ 8 shards
-        _case(32, 4, 3, 2, 16, 8),      # B=256 @ 8 shards
-        _case(256, 4, 3, 2, 16, 8),     # B=2048 @ 8 shards (mega sweep)
+        # (global B / shards).
+        _case(2, 4, 3, 2, 16, 128),      # B=16 @ 8 shards; calib quick grid
+        _case(32, 4, 3, 2, 16, 128),     # B=256 @ 8 shards
+        _case(256, 4, 3, 2, 16, 128),    # B=2048 @ 8 shards; B=256 batches
+        _case(1024, 4, 3, 2, 16, 128),   # B=4096 @ 4 shards
     ]

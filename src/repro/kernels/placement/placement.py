@@ -1,32 +1,38 @@
 """Fused placement kernel (pl.pallas_call + BlockSpec).
 
-The fleet engine's per-tick hot path used to be a *chain* of device
-programs per placement attempt: two ``window_query`` launches (2-core +
-4-core configs), an argmin device-select, and a vmapped ``_bisect``
-scatter cascade for the fan-out commit.  This kernel fuses the whole
-attempt — §IV.B.2 multi-containment query, slot/device selection,
-most-overlapping-track (victim window) selection and the §IV.A.1
-multi-remainder fan-out commit — into ONE launch for the whole
-``[B, Dev, CFG, T, W]`` fleet batch:
+One launch per placement attempt for the whole fleet batch: the §IV.B.2
+multi-containment query, slot/device selection, most-overlapping-track
+(victim window) selection and the §IV.A.1 multi-remainder fan-out commit.
+
+Layout.  The kernel works replica-last: the wrapper transposes the
+engine's ``[B, Dev, CFG, T, W]`` window arrays to ``[Dev, CFG, T, W, B]``
+at the kernel boundary (``ref.to_lanes``) and back afterwards
+(``ref.from_lanes``), so the replicas fill the TPU's 128-lane axis and W
+the sublanes:
 
     grid = (replica blocks,)
-    block: windows [block_b, Dev, CFG, T, W], params [block_b, ...]
+    block: windows [Dev, CFG, T, W, block_b]; q1/dl [Dev, 1, block_b];
+           min_dur [CFG, 1, block_b]; src/do and outputs [1, block_b]
+
+``block_b`` is a multiple of 128, or the whole batch when that is
+smaller (the TPU's rule for the minor block dim).  Per-replica and
+per-device values broadcast along leading dims and every reduction keeps
+its axis, so no shape cast moves data into the minor tile.  The
+boundary transposes are full passes over the window arrays in XLA on
+every attempt; keeping ``FleetState`` replica-last would remove them.
 
 The window arrays are aliased input→output (``input_output_aliases``), so
 the commit is an in-place VMEM update; replicas whose ``do`` mask is off
-are passed through bit-identical.
-
-The kernel body traces ``ref._fused_place_math`` with
-``kernel_safe=True`` (broadcast/compare/reduce ops only, no gather /
-scatter / sort) — the same formula as the oracle, which differs only in
-the device gather/scatter lowering inside ``fanout_commit``
-(``take_along_axis`` + in-place scatter, bit-identical values); the
+are passed through bit-identical.  The body traces
+``ref._fused_place_math``, the same trace as the oracle, so the
 equivalence tests assert exact equality.
 
-VMEM per tile: 6 · block_b · Dev·CFG·T·W · 4 B plus parameter rows —
-≈ 0.3 MB at (block_b=8, Dev=4, CFG=3, T=2, W=16).  Like the window-query
-kernel this is interpret-validated on CPU; real-TPU numbers are a
-ROADMAP item.
+VMEM: 6 window blocks (3 in, 3 out) of Dev·CFG·T·W·block_b · 4 B,
+double-buffered, plus the body's temporaries.  For a described TPU v5e
+at (Dev=4, CFG=3, T=2, W=16) Mosaic needs a scoped limit of 2.9 MiB at
+block_b=128, 5.9 MiB at 256 and 12 MiB at 512, so the default 16 MiB
+holds and no ``vmem_limit_bytes`` is set.  tests/test_tpu_compile.py
+compiles it for v5e at B = 4096, 1024, 2 and 1.
 """
 
 from __future__ import annotations
@@ -37,34 +43,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.placement.ref import _fused_place_math
+from repro.kernels.placement.ref import (
+    _fused_place_math, from_lanes, to_lanes,
+)
+
+#: the TPU lane width: a block's minor dim is a multiple of it or the
+#: whole array dim.
+LANES = 128
+
+
+def block_size(B: int, block_b: int) -> int:
+    """The replica tile for a batch of ``B``: the whole batch when it fits
+    in one tile, else ``block_b``, which must then be lane-aligned."""
+    if B <= block_b:
+        return B
+    if block_b % LANES:
+        raise ValueError(
+            f"placement block_b={block_b} must be a multiple of {LANES} "
+            f"when it tiles a batch of {B} (the TPU's minor block dim)"
+        )
+    return block_b
 
 
 def _placement_kernel(q1_ref, dl_ref, src_ref, do_ref, md_ref, t1_ref,
                       t2_ref, valid_ref, t1_out, t2_out, valid_out, ok_out,
                       sel_out, start_out, dur_out, use4_out, drop_out, *,
                       cfg_pref: int, cfg_fallback: int):
-    t1 = t1_ref[...]                         # [bb, Dev, CFG, T, W]
-    t2 = t2_ref[...]
-    valid = valid_ref[...] != 0
-    q1 = q1_ref[...]                         # [bb, Dev]
-    dl = dl_ref[...]
-    src = src_ref[...]                       # [bb]
-    do = do_ref[...] != 0
-    md = md_ref[...]                         # [bb, CFG]
     nt1, nt2, nv, ok, sel, start, dur, use4, n_drop = _fused_place_math(
-        t1, t2, valid, md, q1, dl, src, do,
-        cfg_pref=cfg_pref, cfg_fallback=cfg_fallback, kernel_safe=True,
+        t1_ref[...], t2_ref[...], valid_ref[...] != 0, md_ref[...],
+        q1_ref[...], dl_ref[...], src_ref[...], do_ref[...] != 0,
+        cfg_pref=cfg_pref, cfg_fallback=cfg_fallback,
     )
     t1_out[...] = nt1
     t2_out[...] = nt2
     valid_out[...] = nv.astype(jnp.int32)
     ok_out[...] = ok.astype(jnp.int32)
-    sel_out[...] = sel.astype(jnp.int32)
+    sel_out[...] = sel
     start_out[...] = start
     dur_out[...] = dur
     use4_out[...] = use4.astype(jnp.int32)
-    drop_out[...] = n_drop.astype(jnp.int32)
+    drop_out[...] = n_drop
 
 
 @functools.partial(
@@ -72,8 +90,8 @@ def _placement_kernel(q1_ref, dl_ref, src_ref, do_ref, md_ref, t1_ref,
     static_argnames=("cfg_pref", "cfg_fallback", "block_b", "interpret"),
 )
 def fused_place(t1, t2, valid, min_dur, q1, dl, src, do, *,
-                cfg_pref: int = 1, cfg_fallback: int = 2, block_b: int = 8,
-                interpret: bool = False):
+                cfg_pref: int = 1, cfg_fallback: int = 2,
+                block_b: int = LANES, interpret: bool = False):
     """Fused placement attempt for a whole fleet batch in one launch.
 
     t1, t2: [B, Dev, CFG, T, W] f32; valid: same shape (bool/int);
@@ -83,56 +101,50 @@ def fused_place(t1, t2, valid, min_dur, q1, dl, src, do, *,
     use4 bool, n_dropped i32)`` — the same tuple as the jnp oracle.
     """
     B, Dev, CFG, T, W = t1.shape
+    bb = block_size(B, block_b)
+    t1, t2, valid, md, q1, dl, src, do = to_lanes(
+        t1, t2, valid, min_dur, q1, dl, src, do
+    )
     valid = valid.astype(jnp.int32)
-    q1 = jnp.broadcast_to(jnp.asarray(q1, jnp.float32), (B, Dev))
-    dl = jnp.broadcast_to(jnp.asarray(dl, jnp.float32), (B, Dev))
-    src = jnp.asarray(src, jnp.int32)
-    do = jnp.asarray(do).astype(jnp.int32)
-    block_b = min(block_b, B)
-    pad = (-B) % block_b
+    do = do.astype(jnp.int32)
+    pad = (-B) % bb
     if pad:
-        padw = ((0, pad),) + ((0, 0),) * 4
-        t1 = jnp.pad(t1, padw)
-        t2 = jnp.pad(t2, padw)
-        valid = jnp.pad(valid, padw)
-        min_dur = jnp.pad(min_dur, ((0, pad), (0, 0)))
-        q1 = jnp.pad(q1, ((0, pad), (0, 0)))
-        dl = jnp.pad(dl, ((0, pad), (0, 0)))
-        src = jnp.pad(src, (0, pad))
-        do = jnp.pad(do, (0, pad))          # padded replicas never commit
-    Bp = t1.shape[0]
+        # padded replicas have do=0, so they never commit
+        lane_pad = lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
+        t1, t2, valid, md, q1, dl, src, do = map(
+            lane_pad, (t1, t2, valid, md, q1, dl, src, do)
+        )
+    Bp = B + pad
 
     win_spec = pl.BlockSpec(
-        (block_b, Dev, CFG, T, W), lambda i: (i, 0, 0, 0, 0)
+        (Dev, CFG, T, W, bb), lambda i: (0, 0, 0, 0, i)
     )
-    devp_spec = pl.BlockSpec((block_b, Dev), lambda i: (i, 0))
-    cfgp_spec = pl.BlockSpec((block_b, CFG), lambda i: (i, 0))
-    rep_spec = pl.BlockSpec((block_b,), lambda i: (i,))
+    devp_spec = pl.BlockSpec((Dev, 1, bb), lambda i: (0, 0, i))
+    cfgp_spec = pl.BlockSpec((CFG, 1, bb), lambda i: (0, 0, i))
+    rep_spec = pl.BlockSpec((1, bb), lambda i: (0, i))
+    win = lambda dt: jax.ShapeDtypeStruct((Dev, CFG, T, W, Bp), dt)
+    rep = lambda dt: jax.ShapeDtypeStruct((1, Bp), dt)
     kernel = functools.partial(
         _placement_kernel, cfg_pref=cfg_pref, cfg_fallback=cfg_fallback
     )
     out = pl.pallas_call(
         kernel,
-        grid=(Bp // block_b,),
+        grid=(Bp // bb,),
         in_specs=[devp_spec, devp_spec, rep_spec, rep_spec, cfgp_spec,
                   win_spec, win_spec, win_spec],
         out_specs=[win_spec, win_spec, win_spec, rep_spec, rep_spec,
                    rep_spec, rep_spec, rep_spec, rep_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, Dev, CFG, T, W), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, Dev, CFG, T, W), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, Dev, CFG, T, W), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            win(jnp.float32), win(jnp.float32), win(jnp.int32),
+            rep(jnp.int32), rep(jnp.int32), rep(jnp.float32),
+            rep(jnp.float32), rep(jnp.int32), rep(jnp.int32),
         ],
         # the commit is an in-place update of the window arrays
         input_output_aliases={5: 0, 6: 1, 7: 2},
         interpret=interpret,
-    )(q1, dl, src, do, min_dur, t1, t2, valid)
-    nt1, nt2, nv, ok, sel, start, dur, use4, n_drop = out
-    return (nt1[:B], nt2[:B], nv[:B].astype(bool), ok[:B].astype(bool),
-            sel[:B], start[:B], dur[:B], use4[:B].astype(bool), n_drop[:B])
+    )(q1, dl, src, do, md, t1, t2, valid)
+    nt1, nt2, nv, ok, sel, start, dur, use4, n_drop = (
+        a[..., :B] for a in out
+    )
+    return from_lanes(nt1, nt2, nv != 0, ok != 0, sel, start, dur,
+                      use4 != 0, n_drop)

@@ -162,3 +162,19 @@ def test_shard_pad():
     assert shard_pad(12, 8) == 4
     assert shard_pad(1, 8) == 7
     assert shard_pad(12, 1) == 0
+
+
+def test_cell_moments_matmuls_run_at_full_precision():
+    """The per-cell sums are matmuls; at the TPU's default precision their
+    f32 operands round to bf16 and a cell mean moves by ~1e-4 relative,
+    so every dot in the reduction must ask for full precision."""
+    from repro.fleet import cell_moments, init_stats
+
+    b = 8
+    jaxpr = jax.make_jaxpr(
+        lambda s, rq, own: cell_moments(s, rq, own, n_cells=2, n_frames=F)
+    )(init_stats(b), np.zeros((b, 4), bool), np.zeros((b,), np.int32))
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2

@@ -282,7 +282,7 @@ def test_fixture_dirs_excluded_from_default_scan():
 def test_window_arithmetic_stays_32bit_under_x64():
     from repro.core.jax_state import compact_tracks, fanout_commit
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         t1 = jnp.asarray(
             np.array([[0.0, 10.0, 30.0, 1e30]], np.float32))
         t2 = jnp.asarray(

@@ -6,12 +6,13 @@ The kernel body traces the same jnp graph as the oracle, so equivalence
 asserts are exact (`assert_array_equal`, no tolerance).
 """
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core.jax_state import BIG
-from repro.kernels.placement.ops import fused_place_op
-from repro.kernels.placement.placement import fused_place
+from repro.kernels.placement.ops import fused_place_op, resolve_backend
+from repro.kernels.placement.placement import block_size, fused_place
 from repro.kernels.placement.ref import SRC_PREF, fused_place_ref
 
 DEV, CFG, T, W = 4, 3, 2, 16
@@ -35,12 +36,13 @@ def _random_case(b, seed=0, do_rate=0.8):
     return t1, t2, valid, md, q1, dl, src, do
 
 
-@pytest.mark.parametrize("b,block_b", [(8, 8), (5, 4), (1, 8), (9, 4)],
+@pytest.mark.parametrize("b,block_b",
+                         [(8, 8), (130, 128), (1, 128), (300, 128)],
                          ids=["exact", "pad", "b1", "pad3"])
 def test_kernel_matches_oracle(b, block_b):
     """Interpret-mode kernel output must equal the jnp oracle exactly —
     including when B is not divisible by block_b (padding path) and at
-    B=1 (clamp path)."""
+    B=1 (one tile of the whole batch)."""
     for seed in range(3):
         args = _random_case(b, seed=seed)
         ref = fused_place_ref(*args)
@@ -57,6 +59,27 @@ def test_op_backends_agree():
     ker = fused_place_op(*args, backend="kernel")
     for r, k in zip(ref, ker):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(k))
+
+
+def test_kernel_backend_says_it_interprets_off_tpu():
+    """backend="kernel" off a TPU runs the interpreter, and says so."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernel compiles on a TPU")
+    with pytest.warns(RuntimeWarning, match="interpret mode"):
+        fused_place_op(*_random_case(2, seed=4), backend="kernel")
+    assert resolve_backend("auto") == (False, False)
+    assert resolve_backend("ref") == (False, False)
+
+
+def test_block_size_is_lane_aligned():
+    """A tile smaller than the batch must be a multiple of the 128 lanes
+    (the TPU's rule for a block's minor dim); a batch that fits in one
+    tile is the whole block."""
+    assert block_size(1, 128) == 1
+    assert block_size(100, 128) == 100
+    assert block_size(4096, 256) == 256
+    with pytest.raises(ValueError, match="multiple of 128"):
+        block_size(256, 8)
 
 
 def test_op_rejects_unknown_backend():

@@ -19,17 +19,8 @@ from repro.launch.sharding import (
 from repro.models.config import ALL_SHAPES, DECODE_32K, LONG_500K, TRAIN_4K
 from repro.models.transformer import Model
 
-def _abstract_mesh(sizes, names):
-    """jax < 0.5 takes AbstractMesh(((name, size), ...)); newer releases
-    take AbstractMesh(sizes, names)."""
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 DRY_ARCHS = [a for a in ARCHS if a != "waste-pipeline"]
 
