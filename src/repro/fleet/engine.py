@@ -226,6 +226,10 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
         _sanitize.check_sched_state(carry[0], "fleet segment input")
 
     def frame_step(carry, xs):
+        # The tick's phases run under named scopes (tick/housekeeping,
+        # tick/requeue, tick/hp, tick/realloc, tick/lp, tick/mask) that
+        # partition the body, so a profiler trace charges every op to one
+        # phase; each stats update sits in the phase that produced it.
         st0, link_free0, rq0, vc0, stats0 = carry
         if p.telemetry:
             # per-device decision counts for obs/: appended once per
@@ -235,256 +239,269 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
         rq_dl, rq_src, rq_ok = rq0
         vc_s, vc_end, vc_dl, vc_src, vc_ok = vc0
         f, v, bws = xs                       # f i32, v [B,Dev] i32, bws [B]
-        base = f.astype(jnp.float32) * FRAME_PERIOD
-        # housekeeping: recycle slots of fully-elapsed windows so the
-        # fixed-W arrays never clog (the batched analog of the serial
-        # engine's per-frame stale-window prune)
-        st = st._replace(win_valid=st.win_valid & (st.win_t2 > base))
-        if p.compact_every > 0:
-            # periodic in-scan compaction: merge abutting per-track windows
-            # so accumulated bisect remainders free up W slots
-            st = jax.lax.cond(
-                f % p.compact_every == p.compact_every - 1,
-                compact_state, lambda s: s, st,
+        with jax.named_scope("tick/housekeeping"):
+            base = f.astype(jnp.float32) * FRAME_PERIOD
+            # recycle slots of fully-elapsed windows so the fixed-W arrays
+            # never clog (the batched analog of the serial engine's
+            # per-frame stale-window prune)
+            st = st._replace(win_valid=st.win_valid & (st.win_t2 > base))
+            if p.compact_every > 0:
+                # periodic in-scan compaction: merge abutting per-track
+                # windows so accumulated bisect remainders free up W slots
+                st = jax.lax.cond(
+                    f % p.compact_every == p.compact_every - 1,
+                    compact_state, lambda s: s, st,
+                )
+            ttime = (p.transfer_bytes * 8.0) / (
+                p.nominal_bw_bps * jnp.maximum(bws, 1e-3)
             )
-
-        ttime = (p.transfer_bytes * 8.0) / (
-            p.nominal_bw_bps * jnp.maximum(bws, 1e-3)
-        )
 
         # -- victim re-queue pass (§IV.B.3 reallocation) -------------------
         # Runs before this tick's frame releases so victims get first pick
         # of the capacity they lost.  A victim whose deadline cannot fit
         # even the 4-core config any more is dropped as missed.
-        now0 = jnp.full((B,), 0.0, jnp.float32) + base
-        min_lp_dur = jnp.minimum(st.min_dur[:, LP2_IDX], st.min_dur[:, LP4_IDX])
         if R > 0:
-            # drop every victim whose deadline cannot fit even the 4-core
-            # config any more (vectorised over all slots; no query needed)
-            expired = rq_ok & (now0[:, None] + min_lp_dur[:, None] > rq_dl)
-            rq_ok = rq_ok & ~expired
-            stats = stats._replace(
-                missed_by_preemption=stats.missed_by_preemption
-                + expired.sum(axis=1, dtype=jnp.int32)
-            )
-            # one placement attempt per tick for the earliest-deadline
-            # survivor (buffered victims rarely outlive a frame period, so
-            # one attempt per tick drains the buffer in practice while
-            # costing a single fused-kernel launch)
-            slot = jnp.argmin(jnp.where(rq_ok, rq_dl, BIG), axis=1)
-            valid_r = rq_ok[rows, slot]
-            dl = rq_dl[rows, slot]
-            src = rq_src[rows, slot]
-            comm_end = jnp.maximum(link_free, now0) + ttime
-            q1 = jnp.where(
-                dev_ids[None, :] == src[:, None], now0[:, None],
-                jnp.maximum(now0, comm_end)[:, None],
-            )
-            dlb = jnp.broadcast_to(dl[:, None], (B, n_dev))
-            st, ok, sel, start, dur, use4, nd = _place_lp(
-                st, q1, dlb, src, valid_r, p
-            )
-            offl = ok & (sel != src)
-            link_free = jnp.where(offl, comm_end, link_free)
-            # the re-placed victim is now the newest commit on its device
-            vc_s, vc_end, vc_dl, vc_src, vc_ok = _vc_commit(
-                (vc_s, vc_end, vc_dl, vc_src, vc_ok), ok, sel, start,
-                start + dur, dl, src
-            )
-            stats = stats._replace(
-                lp_completed=stats.lp_completed + ok,
-                lp_requeued=stats.lp_requeued + ok,
-                lp_offloaded=stats.lp_offloaded + offl,
-                lp_four_core=stats.lp_four_core + (ok & use4),
-                comm_busy=stats.comm_busy + jnp.where(offl, ttime, 0.0),
-                remainders_dropped=stats.remainders_dropped + nd,
-            )
-            rq_ok = rq_ok.at[rows, slot].set(valid_r & ~ok)
-
-        for d in range(n_dev):
-            t_rel = base + d * (FRAME_PERIOD / n_dev) * p.stagger
-            now = jnp.full((B,), 0.0, jnp.float32) + t_rel
-            vd = v[:, d].astype(jnp.int32)
-            has_frame = vd >= 0
-
-            # -- HP: immediate slot on the source device -------------------
-            # The detector always runs at frame release (§IV.B.1): if the
-            # strict-containment query finds no reserved gap, HP requests a
-            # preemption.  A live cached victim ⇒ committed preemption (the
-            # victim loses its credit and is re-queued, [now, now+dur) is
-            # evicted from every availability list); no victim ⇒ the serial
-            # engine's "no-preemptable" admission failure — the frame dies.
-            hp_dur = st.min_dur[:, HP_IDX]
-            hp_found, hp_start = _hp_query(st, d, now, hp_dur, p.hp_deadline)
-            if R > 0:
-                # the serial engine evicts only a task whose reserved slot
-                # overlaps the requested HP window (§IV.B.3)
-                victim_live = (vc_ok[:, d] & (vc_end[:, d] > now)
-                               & (vc_s[:, d] < now + hp_dur))
-            else:
-                # reallocation disabled: legacy capacity-eviction semantics
-                # (HP always runs, victims implicitly keep their credit)
-                victim_live = jnp.ones((B,), bool)
-            hp_ok = has_frame & (hp_found | victim_live)
-            preempt = has_frame & ~hp_found & victim_live
-            hp_fail = has_frame & ~hp_found & ~victim_live
-            hp_start = jnp.where(hp_found, hp_start, now)
-            st, nd = _hp_commit(st, d, hp_start, hp_start + hp_dur, hp_ok)
-            stats = stats._replace(
-                remainders_dropped=stats.remainders_dropped + nd
-            )
-
-            if R > 0:
-                vc_ok = vc_ok.at[:, d].set(vc_ok[:, d] & ~preempt)
-                # the victim's placement-time completion credit is revoked;
-                # re-earned on re-placement or it becomes a miss
-                stats = stats._replace(lp_completed=stats.lp_completed
-                                       - preempt)
-
-                # immediate reallocation attempt (§VI.A: the serial engine
-                # re-enters the victim at HP-commit time, and that path
-                # succeeds in the common case — deferring a whole frame
-                # period would eat most of the victim's deadline budget)
-                dl_v = vc_dl[:, d]
-                src_v = vc_src[:, d]
-                comm_end = jnp.maximum(link_free, now) + ttime
-                q1 = jnp.where(
-                    dev_ids[None, :] == src_v[:, None], now[:, None],
-                    jnp.maximum(now, comm_end)[:, None],
-                )
-                st, ok_v, sel_v, start_v, dur_v, use4_v, nd = _place_lp(
-                    st, q1, jnp.broadcast_to(dl_v[:, None], (B, n_dev)),
-                    src_v, preempt, p,
-                )
-                offl_v = ok_v & (sel_v != src_v)
-                link_free = jnp.where(offl_v, comm_end, link_free)
-                vc_s, vc_end, vc_dl, vc_src, vc_ok = _vc_commit(
-                    (vc_s, vc_end, vc_dl, vc_src, vc_ok), ok_v, sel_v,
-                    start_v, start_v + dur_v, dl_v, src_v,
-                )
-                stats = stats._replace(
-                    lp_completed=stats.lp_completed + ok_v,
-                    lp_requeued=stats.lp_requeued + ok_v,
-                    lp_offloaded=stats.lp_offloaded + offl_v,
-                    lp_four_core=stats.lp_four_core + (ok_v & use4_v),
-                    comm_busy=stats.comm_busy
-                    + jnp.where(offl_v, ttime, 0.0),
-                    remainders_dropped=stats.remainders_dropped + nd,
-                )
-
-                # unplaced victims enter the bounded re-queue buffer for
-                # next-tick retries; a full buffer drops the victim
-                # (counted missed, not silent)
-                free = jnp.argmin(rq_ok, axis=1)
-                has_free = ~rq_ok.all(axis=1)
-                unplaced = preempt & ~ok_v
-                push = unplaced & has_free
-                rq_dl = rq_dl.at[rows, free].set(
-                    jnp.where(push, dl_v, rq_dl[rows, free])
-                )
-                rq_src = rq_src.at[rows, free].set(
-                    jnp.where(push, src_v, rq_src[rows, free])
-                )
-                rq_ok = rq_ok.at[rows, free].set(rq_ok[rows, free] | push)
+            with jax.named_scope("tick/requeue"):
+                now0 = jnp.full((B,), 0.0, jnp.float32) + base
+                min_lp_dur = jnp.minimum(st.min_dur[:, LP2_IDX],
+                                         st.min_dur[:, LP4_IDX])
+                # drop every victim whose deadline cannot fit even the 4-core
+                # config any more (vectorised over all slots; no query needed)
+                expired = rq_ok & (now0[:, None] + min_lp_dur[:, None] > rq_dl)
+                rq_ok = rq_ok & ~expired
                 stats = stats._replace(
                     missed_by_preemption=stats.missed_by_preemption
-                    + (unplaced & ~has_free),
+                    + expired.sum(axis=1, dtype=jnp.int32)
                 )
-
-            stats = stats._replace(
-                frames=stats.frames + has_frame,
-                hp_completed=stats.hp_completed + hp_ok,
-                hp_failed=stats.hp_failed + hp_fail,
-                # committed preemptions only: an admission failure that
-                # found nothing to evict is hp_failed, not a preemption
-                hp_preempted=stats.hp_preempted + preempt,
-            )
-
-            # -- LP: up to 4 DNN tasks once HP completes -------------------
-            n_lp = jnp.where(hp_ok, jnp.clip(vd, 0, MAX_LP), 0)
-            release = hp_start + hp_dur
-            deadline = now + p.lp_deadline_factor * FRAME_PERIOD
-            frame_ok = hp_ok
-            src_d = jnp.full((B,), d, jnp.int32)
-            if p.telemetry:
-                lp_placed_d = jnp.zeros((B,), jnp.int32)
-            for k in range(MAX_LP):
-                mask = hp_ok & (k < n_lp)
-                comm_end = jnp.maximum(link_free, release) + ttime
-                # remote devices can only start once their transfer lands
+                # one placement attempt per tick for the earliest-deadline
+                # survivor (buffered victims rarely outlive a frame period, so
+                # one attempt per tick drains the buffer in practice while
+                # costing a single fused-kernel launch)
+                slot = jnp.argmin(jnp.where(rq_ok, rq_dl, BIG), axis=1)
+                valid_r = rq_ok[rows, slot]
+                dl = rq_dl[rows, slot]
+                src = rq_src[rows, slot]
+                comm_end = jnp.maximum(link_free, now0) + ttime
                 q1 = jnp.where(
-                    dev_ids[None, :] == d, release[:, None],
-                    jnp.maximum(release, comm_end)[:, None],
+                    dev_ids[None, :] == src[:, None], now0[:, None],
+                    jnp.maximum(now0, comm_end)[:, None],
                 )
-                dl = jnp.broadcast_to(deadline[:, None], (B, n_dev))
+                dlb = jnp.broadcast_to(dl[:, None], (B, n_dev))
                 st, ok, sel, start, dur, use4, nd = _place_lp(
-                    st, q1, dl, src_d, mask, p
+                    st, q1, dlb, src, valid_r, p
                 )
-                offl = ok & (sel != d)
+                offl = ok & (sel != src)
                 link_free = jnp.where(offl, comm_end, link_free)
+                # the re-placed victim is now the newest commit on its device
                 vc_s, vc_end, vc_dl, vc_src, vc_ok = _vc_commit(
                     (vc_s, vc_end, vc_dl, vc_src, vc_ok), ok, sel, start,
-                    start + dur, deadline, src_d,
+                    start + dur, dl, src
                 )
                 stats = stats._replace(
-                    lp_spawned=stats.lp_spawned + mask,
                     lp_completed=stats.lp_completed + ok,
-                    lp_failed=stats.lp_failed + (mask & ~ok),
+                    lp_requeued=stats.lp_requeued + ok,
                     lp_offloaded=stats.lp_offloaded + offl,
                     lp_four_core=stats.lp_four_core + (ok & use4),
-                    start_delay_sum=stats.start_delay_sum
-                    + jnp.where(ok, start - release, 0.0),
                     comm_busy=stats.comm_busy + jnp.where(offl, ttime, 0.0),
                     remainders_dropped=stats.remainders_dropped + nd,
                 )
-                frame_ok = frame_ok & (ok | (k >= n_lp))
+                rq_ok = rq_ok.at[rows, slot].set(valid_r & ~ok)
+
+        for d in range(n_dev):
+            with jax.named_scope("tick/hp"):
+                t_rel = base + d * (FRAME_PERIOD / n_dev) * p.stagger
+                now = jnp.full((B,), 0.0, jnp.float32) + t_rel
+                vd = v[:, d].astype(jnp.int32)
+                has_frame = vd >= 0
+
+                # -- HP: immediate slot on the source device ---------------
+                # The detector always runs at frame release (§IV.B.1): if
+                # the strict-containment query finds no reserved gap, HP
+                # requests a preemption.  A live cached victim ⇒ committed
+                # preemption (the victim loses its credit and is re-queued,
+                # [now, now+dur) is evicted from every availability list);
+                # no victim ⇒ the serial engine's "no-preemptable"
+                # admission failure — the frame dies.
+                hp_dur = st.min_dur[:, HP_IDX]
+                hp_found, hp_start = _hp_query(st, d, now, hp_dur,
+                                               p.hp_deadline)
+                if R > 0:
+                    # the serial engine evicts only a task whose reserved
+                    # slot overlaps the requested HP window (§IV.B.3)
+                    victim_live = (vc_ok[:, d] & (vc_end[:, d] > now)
+                                   & (vc_s[:, d] < now + hp_dur))
+                else:
+                    # reallocation disabled: legacy capacity-eviction
+                    # semantics (HP always runs, victims implicitly keep
+                    # their credit)
+                    victim_live = jnp.ones((B,), bool)
+                hp_ok = has_frame & (hp_found | victim_live)
+                preempt = has_frame & ~hp_found & victim_live
+                hp_fail = has_frame & ~hp_found & ~victim_live
+                hp_start = jnp.where(hp_found, hp_start, now)
+                st, nd = _hp_commit(st, d, hp_start, hp_start + hp_dur,
+                                    hp_ok)
+                stats = stats._replace(
+                    remainders_dropped=stats.remainders_dropped + nd,
+                    frames=stats.frames + has_frame,
+                    hp_completed=stats.hp_completed + hp_ok,
+                    hp_failed=stats.hp_failed + hp_fail,
+                    # committed preemptions only: an admission failure that
+                    # found nothing to evict is hp_failed, not a preemption
+                    hp_preempted=stats.hp_preempted + preempt,
+                )
+                if R > 0:
+                    vc_ok = vc_ok.at[:, d].set(vc_ok[:, d] & ~preempt)
+                    # the victim's placement-time completion credit is
+                    # revoked; re-earned on re-placement or it becomes a
+                    # miss
+                    stats = stats._replace(lp_completed=stats.lp_completed
+                                           - preempt)
+
+            if R > 0:
+                with jax.named_scope("tick/realloc"):
+                    # immediate reallocation attempt (§VI.A: the serial
+                    # engine re-enters the victim at HP-commit time, and
+                    # that path succeeds in the common case — deferring a
+                    # whole frame period would eat most of the victim's
+                    # deadline budget)
+                    dl_v = vc_dl[:, d]
+                    src_v = vc_src[:, d]
+                    comm_end = jnp.maximum(link_free, now) + ttime
+                    q1 = jnp.where(
+                        dev_ids[None, :] == src_v[:, None], now[:, None],
+                        jnp.maximum(now, comm_end)[:, None],
+                    )
+                    st, ok_v, sel_v, start_v, dur_v, use4_v, nd = _place_lp(
+                        st, q1, jnp.broadcast_to(dl_v[:, None], (B, n_dev)),
+                        src_v, preempt, p,
+                    )
+                    offl_v = ok_v & (sel_v != src_v)
+                    link_free = jnp.where(offl_v, comm_end, link_free)
+                    vc_s, vc_end, vc_dl, vc_src, vc_ok = _vc_commit(
+                        (vc_s, vc_end, vc_dl, vc_src, vc_ok), ok_v, sel_v,
+                        start_v, start_v + dur_v, dl_v, src_v,
+                    )
+                    stats = stats._replace(
+                        lp_completed=stats.lp_completed + ok_v,
+                        lp_requeued=stats.lp_requeued + ok_v,
+                        lp_offloaded=stats.lp_offloaded + offl_v,
+                        lp_four_core=stats.lp_four_core + (ok_v & use4_v),
+                        comm_busy=stats.comm_busy
+                        + jnp.where(offl_v, ttime, 0.0),
+                        remainders_dropped=stats.remainders_dropped + nd,
+                    )
+
+                    # unplaced victims enter the bounded re-queue buffer
+                    # for next-tick retries; a full buffer drops the victim
+                    # (counted missed, not silent)
+                    free = jnp.argmin(rq_ok, axis=1)
+                    has_free = ~rq_ok.all(axis=1)
+                    unplaced = preempt & ~ok_v
+                    push = unplaced & has_free
+                    rq_dl = rq_dl.at[rows, free].set(
+                        jnp.where(push, dl_v, rq_dl[rows, free])
+                    )
+                    rq_src = rq_src.at[rows, free].set(
+                        jnp.where(push, src_v, rq_src[rows, free])
+                    )
+                    rq_ok = rq_ok.at[rows, free].set(
+                        rq_ok[rows, free] | push)
+                    stats = stats._replace(
+                        missed_by_preemption=stats.missed_by_preemption
+                        + (unplaced & ~has_free),
+                    )
+
+            with jax.named_scope("tick/lp"):
+                # -- LP: up to 4 DNN tasks once HP completes ---------------
+                n_lp = jnp.where(hp_ok, jnp.clip(vd, 0, MAX_LP), 0)
+                release = hp_start + hp_dur
+                deadline = now + p.lp_deadline_factor * FRAME_PERIOD
+                frame_ok = hp_ok
+                src_d = jnp.full((B,), d, jnp.int32)
                 if p.telemetry:
-                    lp_placed_d = lp_placed_d + ok.astype(jnp.int32)
-            stats = stats._replace(
-                frames_completed=stats.frames_completed
-                + (has_frame & frame_ok)
-            )
+                    lp_placed_d = jnp.zeros((B,), jnp.int32)
+                for k in range(MAX_LP):
+                    mask = hp_ok & (k < n_lp)
+                    comm_end = jnp.maximum(link_free, release) + ttime
+                    # remote devices can only start once their transfer
+                    # lands
+                    q1 = jnp.where(
+                        dev_ids[None, :] == d, release[:, None],
+                        jnp.maximum(release, comm_end)[:, None],
+                    )
+                    dl = jnp.broadcast_to(deadline[:, None], (B, n_dev))
+                    st, ok, sel, start, dur, use4, nd = _place_lp(
+                        st, q1, dl, src_d, mask, p
+                    )
+                    offl = ok & (sel != d)
+                    link_free = jnp.where(offl, comm_end, link_free)
+                    vc_s, vc_end, vc_dl, vc_src, vc_ok = _vc_commit(
+                        (vc_s, vc_end, vc_dl, vc_src, vc_ok), ok, sel, start,
+                        start + dur, deadline, src_d,
+                    )
+                    stats = stats._replace(
+                        lp_spawned=stats.lp_spawned + mask,
+                        lp_completed=stats.lp_completed + ok,
+                        lp_failed=stats.lp_failed + (mask & ~ok),
+                        lp_offloaded=stats.lp_offloaded + offl,
+                        lp_four_core=stats.lp_four_core + (ok & use4),
+                        start_delay_sum=stats.start_delay_sum
+                        + jnp.where(ok, start - release, 0.0),
+                        comm_busy=stats.comm_busy
+                        + jnp.where(offl, ttime, 0.0),
+                        remainders_dropped=stats.remainders_dropped + nd,
+                    )
+                    frame_ok = frame_ok & (ok | (k >= n_lp))
+                    if p.telemetry:
+                        lp_placed_d = lp_placed_d + ok.astype(jnp.int32)
+                stats = stats._replace(
+                    frames_completed=stats.frames_completed
+                    + (has_frame & frame_ok)
+                )
             if p.telemetry:
                 pd_run.append(hp_ok)
                 pd_fail.append(hp_fail)
                 pd_preempt.append(preempt)
                 pd_lp.append(lp_placed_d)
-        if sanitize:
-            _sanitize.check_windows(
-                st.win_t1, st.win_t2, st.win_valid, "fleet tick"
+        with jax.named_scope("tick/mask"):
+            if sanitize:
+                _sanitize.check_windows(
+                    st.win_t1, st.win_t2, st.win_valid, "fleet tick"
+                )
+                _sanitize.check(
+                    jnp.all(~vc_ok | (vc_s <= vc_end)),
+                    "victim cache corrupt (fleet tick): a live entry has "
+                    "start > end",
+                )
+                _sanitize.check(
+                    jnp.all(link_free >= 0.0),
+                    "negative link_free (fleet tick): {lf}",
+                    lf=jnp.min(link_free),
+                )
+            new = (st, link_free, (rq_dl, rq_src, rq_ok),
+                   (vc_s, vc_end, vc_dl, vc_src, vc_ok), stats)
+            # mask padded ticks (beyond the true trace) to exact no-ops so
+            # a padded segment is bit-identical to an unsegmented run
+            active = f < n_frames
+            out = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(active, n, o), new, carry
             )
-            _sanitize.check(
-                jnp.all(~vc_ok | (vc_s <= vc_end)),
-                "victim cache corrupt (fleet tick): a live entry has "
-                "start > end",
-            )
-            _sanitize.check(
-                jnp.all(link_free >= 0.0),
-                "negative link_free (fleet tick): {lf}",
-                lf=jnp.min(link_free),
-            )
-        new = (st, link_free, (rq_dl, rq_src, rq_ok),
-               (vc_s, vc_end, vc_dl, vc_src, vc_ok), stats)
-        # mask padded ticks (beyond the true trace) to exact no-ops so a
-        # padded segment is bit-identical to an unsegmented run
-        active = f < n_frames
-        out = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(active, n, o), new, carry
-        )
-        if not p.telemetry:
-            return out, None
-        # read-only capture from the post-mask carry: the per-device
-        # decision counts are already zero on padded ticks (padded trace
-        # values are -1, so has_frame is False everywhere)
-        def stack_i32(xs_):
-            return jnp.stack(xs_, axis=1).astype(jnp.int32)
+            if not p.telemetry:
+                return out, None
 
-        ys = _telemetry.capture_tick(
-            out[0], out[1], out[2][2], stats0, out[4], base, bws,
-            p.nominal_bw_bps, stack_i32(pd_run), stack_i32(pd_fail),
-            stack_i32(pd_preempt), jnp.stack(pd_lp, axis=1),
-        )
-        return out, ys
+            # read-only capture from the post-mask carry: the per-device
+            # decision counts are already zero on padded ticks (padded
+            # trace values are -1, so has_frame is False everywhere)
+            def stack_i32(xs_):
+                return jnp.stack(xs_, axis=1).astype(jnp.int32)
+
+            ys = _telemetry.capture_tick(
+                out[0], out[1], out[2][2], stats0, out[4], base, bws,
+                p.nominal_bw_bps, stack_i32(pd_run), stack_i32(pd_fail),
+                stack_i32(pd_preempt), jnp.stack(pd_lp, axis=1),
+            )
+            return out, ys
 
     S = values.shape[0]
     xs = (f0 + jnp.arange(S, dtype=jnp.int32),
@@ -625,51 +642,55 @@ def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
         S = max(p.telemetry_every, S - S % p.telemetry_every)
     n_seg = -(-F // S)
     pad = n_seg * S - F
-    values = jnp.asarray(values, jnp.int32)
-    bw_scale = jnp.broadcast_to(
-        jnp.asarray(bw_scale, jnp.float32), (F, B)
-    )
-    if pad:
-        # padded frames carry no workload and are masked off inside the
-        # scan anyway; -1 == "no frame released"
-        values = jnp.concatenate(
-            [values, jnp.full((pad, B, n_dev), -1, jnp.int32)]
-        )
-        bw_scale = jnp.concatenate(
-            [bw_scale, jnp.ones((pad, B), jnp.float32)]
-        )
-    if pad_b:
-        # pad the batch so it splits evenly across mesh shards: padded
-        # replicas get no workload (-1 frames), so they advance as pure
-        # no-ops and their (zero) stats rows are trimmed below
-        values = jnp.concatenate(
-            [values, jnp.full(values.shape[:1] + (pad_b, n_dev), -1,
-                              jnp.int32)], axis=1,
-        )
-        bw_scale = jnp.concatenate(
-            [bw_scale, jnp.ones(bw_scale.shape[:1] + (pad_b,),
-                                jnp.float32)], axis=1,
-        )
-    carry = initial_carry(fleet, pad_b)
-    if sharded:
-        # commit the carry and the workload to the mesh once: the donated
-        # buffers then round-trip through every segment without a
-        # resharding copy, and each segment's workload slice is already
-        # split across the devices
-        mesh = _mesh.fleet_mesh(shards)
-        carry = _mesh.put_sharded(carry, mesh)
-        values, bw_scale = _mesh.put_sharded((values, bw_scale), mesh,
-                                             batch_axis=1)
-    nf = jnp.asarray(F, jnp.int32)
     sanitized = _sanitize.enabled()
     telem_segs = []
     with _profile.maybe_jax_trace():
+        with _profile.span("fleet/put"):
+            values = jnp.asarray(values, jnp.int32)
+            bw_scale = jnp.broadcast_to(
+                jnp.asarray(bw_scale, jnp.float32), (F, B)
+            )
+            if pad:
+                # padded frames carry no workload and are masked off inside
+                # the scan anyway; -1 == "no frame released"
+                values = jnp.concatenate(
+                    [values, jnp.full((pad, B, n_dev), -1, jnp.int32)]
+                )
+                bw_scale = jnp.concatenate(
+                    [bw_scale, jnp.ones((pad, B), jnp.float32)]
+                )
+            if pad_b:
+                # pad the batch so it splits evenly across mesh shards:
+                # padded replicas get no workload (-1 frames), so they
+                # advance as pure no-ops and their (zero) stats rows are
+                # trimmed below
+                values = jnp.concatenate(
+                    [values, jnp.full(values.shape[:1] + (pad_b, n_dev), -1,
+                                      jnp.int32)], axis=1,
+                )
+                bw_scale = jnp.concatenate(
+                    [bw_scale, jnp.ones(bw_scale.shape[:1] + (pad_b,),
+                                        jnp.float32)], axis=1,
+                )
+            carry = initial_carry(fleet, pad_b)
+            if sharded:
+                # commit the carry and the workload to the mesh once: the
+                # donated buffers then round-trip through every segment
+                # without a resharding copy, and each segment's workload
+                # slice is already split across the devices
+                mesh = _mesh.fleet_mesh(shards)
+                carry = _mesh.put_sharded(carry, mesh)
+                values, bw_scale = _mesh.put_sharded((values, bw_scale),
+                                                     mesh, batch_axis=1)
+            nf = jnp.asarray(F, jnp.int32)
         for i in range(n_seg):
             seg_args = (
                 carry, values[i * S:(i + 1) * S],
                 bw_scale[i * S:(i + 1) * S],
                 jnp.asarray(i * S, jnp.int32), nf,
             )
+            # times the dispatch: the segment runs asynchronously, so the
+            # device work shows in a profiler trace, not in this span
             with _profile.span("fleet/segment"):
                 if sanitized:
                     checked = (_run_segment_sharded_checked(p) if sharded
@@ -699,9 +720,8 @@ def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
     )
     if not p.telemetry:
         return out, stats
-    with _profile.span("fleet/telemetry_host_transfer"):
-        record = _telemetry.assemble(
-            telem_segs, n_frames=F, every=p.telemetry_every,
-            nominal_bw_bps=p.nominal_bw_bps, n_replicas=B,
-        )
+    record = _telemetry.assemble(
+        telem_segs, n_frames=F, every=p.telemetry_every,
+        nominal_bw_bps=p.nominal_bw_bps, n_replicas=B,
+    )
     return out, stats, record

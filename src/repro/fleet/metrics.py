@@ -203,33 +203,35 @@ def cell_moments(stats: FleetStats, rq_valid, owner, *, n_cells: int,
     (mean first, then Σ(x−mean)²) keeps f32 variance stable at 10⁶
     replicas.
     """
-    pending = rq_valid.sum(axis=1, dtype=jnp.int32)
-    rates = _device_rates(stats, pending, n_frames)
-    resid = (stats.lp_spawned - stats.lp_completed - stats.lp_failed
-             - stats.missed_by_preemption - pending).astype(jnp.int32)
-    rates["conservation_residual"] = resid.astype(jnp.float32)
-    mat = jnp.stack(list(rates.values()), axis=1)          # [B, K]
-    # owner == -1 matches no cell column, so padding drops out of every
-    # count/sum/max below without an explicit mask
-    oh = (owner[:, None] == jnp.arange(n_cells, dtype=jnp.int32)[None, :]
-          ).astype(jnp.float32)                            # [B, C]
-    count = oh.sum(axis=0)
-    # full f32 matmuls: the TPU's default precision rounds the operands
-    # to bf16, which moves a cell mean by ~1e-4 relative
-    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
-    sums = dot(oh.T, mat)                                  # [C, K]
-    if axis_name is not None:
-        count, sums = jax.lax.psum((count, sums), axis_name)
-    mean = sums / jnp.maximum(count, 1.0)[:, None]
-    centred = mat - mean[jnp.clip(owner, 0)]
-    m2 = dot(oh.T, centred * centred)
-    resid_max = jnp.max(
-        jnp.where(oh > 0, jnp.abs(resid)[:, None], 0), axis=0
-    ).astype(jnp.int32)
-    if axis_name is not None:
-        m2 = jax.lax.psum(m2, axis_name)
-        resid_max = jax.lax.pmax(resid_max, axis_name)
-    return CellMoments(count, mean, m2, resid_max)
+    with jax.named_scope("sweep/cell_moments"):
+        pending = rq_valid.sum(axis=1, dtype=jnp.int32)
+        rates = _device_rates(stats, pending, n_frames)
+        resid = (stats.lp_spawned - stats.lp_completed - stats.lp_failed
+                 - stats.missed_by_preemption - pending).astype(jnp.int32)
+        rates["conservation_residual"] = resid.astype(jnp.float32)
+        mat = jnp.stack(list(rates.values()), axis=1)          # [B, K]
+        # owner == -1 matches no cell column, so padding drops out of every
+        # count/sum/max below without an explicit mask
+        oh = (owner[:, None] == jnp.arange(n_cells, dtype=jnp.int32)[None, :]
+              ).astype(jnp.float32)                            # [B, C]
+        count = oh.sum(axis=0)
+        # full f32 matmuls: the TPU's default precision rounds the operands
+        # to bf16, which moves a cell mean by ~1e-4 relative
+        dot = functools.partial(jnp.matmul,
+                                precision=jax.lax.Precision.HIGHEST)
+        sums = dot(oh.T, mat)                                  # [C, K]
+        if axis_name is not None:
+            count, sums = jax.lax.psum((count, sums), axis_name)
+        mean = sums / jnp.maximum(count, 1.0)[:, None]
+        centred = mat - mean[jnp.clip(owner, 0)]
+        m2 = dot(oh.T, centred * centred)
+        resid_max = jnp.max(
+            jnp.where(oh > 0, jnp.abs(resid)[:, None], 0), axis=0
+        ).astype(jnp.int32)
+        if axis_name is not None:
+            m2 = jax.lax.psum(m2, axis_name)
+            resid_max = jax.lax.pmax(resid_max, axis_name)
+        return CellMoments(count, mean, m2, resid_max)
 
 
 def merge_cell_moments(a: Optional[CellMoments],

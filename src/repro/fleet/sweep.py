@@ -37,6 +37,7 @@ from repro.fleet.metrics import (
 )
 from repro.fleet.scenarios import make_workload
 from repro.fleet.state import make_fleet
+from repro.obs.profile import maybe_jax_trace, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,11 +88,21 @@ def _build_population(cfg: SweepConfig):
 
 
 def run_sweep(cfg: SweepConfig) -> dict:
-    """Returns {"scenario@congestion": summary} plus a "_sweep" header."""
-    if cfg.mesh_shards:
-        return _run_sweep_sharded(cfg)
+    """Returns {"scenario@congestion": summary} plus a "_sweep" header.
+
+    With ``$REPRO_PROFILE_DIR`` set, the whole sweep is one profiler
+    trace (``obs.profile.maybe_jax_trace``): its host spans and the
+    device ops of every batch."""
+    with maybe_jax_trace():
+        if cfg.mesh_shards:
+            return _run_sweep_sharded(cfg)
+        return _run_sweep_host(cfg)
+
+
+def _run_sweep_host(cfg: SweepConfig) -> dict:
     p = cfg.fleet_params()
-    cells, values, bw_scale, owners = _build_population(cfg)
+    with span("sweep/workload"):
+        cells, values, bw_scale, owners = _build_population(cfg)
     total = values.shape[1]
 
     # Fan into fixed-size batches (pad the tail so every launch reuses the
@@ -104,42 +115,47 @@ def run_sweep(cfg: SweepConfig) -> dict:
     per_replica: list[FleetStats] = []
     per_replica_pending: list[np.ndarray] = []
     for b0 in range(0, values.shape[1], bs):
-        fleet = make_fleet(bs, cfg.n_devices, requeue_slots=p.requeue_slots)
-        state, stats = fleet_run(
-            fleet,
-            values[:, b0:b0 + bs],
-            bw_scale[:, b0:b0 + bs],
-            params=p,
-        )
-        per_replica.append(jax_to_np(stats))
-        # end-of-run re-queue occupancy: closes the LP conservation
-        # identity that summarize() checks per cell
-        per_replica_pending.append(
-            np.asarray(state.rq_valid).sum(axis=1).astype(np.int64)
-        )
-    merged = FleetStats(*(
-        np.concatenate([getattr(s, f) for s in per_replica])[:total]
-        for f in FleetStats._fields
-    ))
-    pending = np.concatenate(per_replica_pending)[:total]
-
-    out = {
-        "_sweep": {
-            "cells": [f"{s}@{c:g}" for s, c in cells],
-            "n_seeds": cfg.n_seeds,
-            "n_frames": cfg.n_frames,
-            "total_replicas": int(total),
-            "batch_size": bs,
+        with span("sweep/batch"):
+            with span("sweep/make_fleet"):
+                fleet = make_fleet(bs, cfg.n_devices,
+                                   requeue_slots=p.requeue_slots)
+            state, stats = fleet_run(
+                fleet,
+                values[:, b0:b0 + bs],
+                bw_scale[:, b0:b0 + bs],
+                params=p,
+            )
+            # the host waits here for the batch's device work
+            with span("sweep/collect"):
+                per_replica.append(jax_to_np(stats))
+                # end-of-run re-queue occupancy: closes the LP
+                # conservation identity that summarize() checks per cell
+                per_replica_pending.append(
+                    np.asarray(state.rq_valid).sum(axis=1).astype(np.int64)
+                )
+    with span("sweep/summarize"):
+        merged = FleetStats(*(
+            np.concatenate([getattr(s, f) for s in per_replica])[:total]
+            for f in FleetStats._fields
+        ))
+        pending = np.concatenate(per_replica_pending)[:total]
+        out = {
+            "_sweep": {
+                "cells": [f"{s}@{c:g}" for s, c in cells],
+                "n_seeds": cfg.n_seeds,
+                "n_frames": cfg.n_frames,
+                "total_replicas": int(total),
+                "batch_size": bs,
+            }
         }
-    }
-    for ci, (scen, cong) in enumerate(cells):
-        sel = owners == ci
-        cell_stats = FleetStats(
-            *(getattr(merged, f)[sel] for f in FleetStats._fields)
-        )
-        out[f"{scen}@{cong:g}"] = summarize(
-            cell_stats, cfg.n_frames, rq_pending=pending[sel]
-        )
+        for ci, (scen, cong) in enumerate(cells):
+            sel = owners == ci
+            cell_stats = FleetStats(
+                *(getattr(merged, f)[sel] for f in FleetStats._fields)
+            )
+            out[f"{scen}@{cong:g}"] = summarize(
+                cell_stats, cfg.n_frames, rq_pending=pending[sel]
+            )
     return out
 
 
@@ -174,7 +190,8 @@ def _cell_reducer(shards: int, n_cells: int, n_frames: int):
 def _run_sweep_sharded(cfg: SweepConfig) -> dict:
     p = cfg.fleet_params()
     shards = p.mesh_shards
-    cells, values, bw_scale, owners = _build_population(cfg)
+    with span("sweep/workload"):
+        cells, values, bw_scale, owners = _build_population(cfg)
     total = values.shape[1]
 
     # batch size must split across the mesh; pad the tail with owner=-1
@@ -190,22 +207,29 @@ def _run_sweep_sharded(cfg: SweepConfig) -> dict:
     reducer = _cell_reducer(shards, len(cells), cfg.n_frames)
     moments = None
     for b0 in range(0, values.shape[1], bs):
-        fleet = make_fleet(bs, cfg.n_devices, requeue_slots=p.requeue_slots)
-        state, stats = fleet_run(
-            fleet,
-            values[:, b0:b0 + bs],
-            bw_scale[:, b0:b0 + bs],
-            params=p,
-        )
-        owner = _mesh.put_sharded(
-            np.ascontiguousarray(owners[b0:b0 + bs]),
-            _mesh.fleet_mesh(shards),
-        )
-        batch_moments = reducer(stats, state.rq_valid, owner)
-        # the one host transfer per batch: [C] + 2×[C, K] moment arrays
-        moments = merge_cell_moments(
-            moments, jax.tree_util.tree_map(np.asarray, batch_moments)
-        )
+        with span("sweep/batch"):
+            with span("sweep/make_fleet"):
+                fleet = make_fleet(bs, cfg.n_devices,
+                                   requeue_slots=p.requeue_slots)
+            state, stats = fleet_run(
+                fleet,
+                values[:, b0:b0 + bs],
+                bw_scale[:, b0:b0 + bs],
+                params=p,
+            )
+            owner = _mesh.put_sharded(
+                np.ascontiguousarray(owners[b0:b0 + bs]),
+                _mesh.fleet_mesh(shards),
+            )
+            # the host waits here for the batch's device work
+            with span("sweep/reduce"):
+                batch_moments = reducer(stats, state.rq_valid, owner)
+                # the one host transfer per batch: [C] + 2×[C, K] moment
+                # arrays
+                moments = merge_cell_moments(
+                    moments,
+                    jax.tree_util.tree_map(np.asarray, batch_moments)
+                )
 
     keys = cell_rate_keys()
     summaries = summarize_cells(moments, keys)

@@ -21,6 +21,10 @@ its axis, so no shape cast moves data into the minor tile.  The
 boundary transposes are full passes over the window arrays in XLA on
 every attempt; keeping ``FleetState`` replica-last would remove them.
 
+Under a profiler the boundary work (``to_lanes``, ``from_lanes``, the
+dtype casts and lane padding) runs under the named scope
+``placement/layout`` and the launch under ``placement/kernel``.
+
 The window arrays are aliased input→output (``input_output_aliases``), so
 the commit is an in-place VMEM update; replicas whose ``do`` mask is off
 are passed through bit-identical.  The body traces
@@ -102,18 +106,20 @@ def fused_place(t1, t2, valid, min_dur, q1, dl, src, do, *,
     """
     B, Dev, CFG, T, W = t1.shape
     bb = block_size(B, block_b)
-    t1, t2, valid, md, q1, dl, src, do = to_lanes(
-        t1, t2, valid, min_dur, q1, dl, src, do
-    )
-    valid = valid.astype(jnp.int32)
-    do = do.astype(jnp.int32)
     pad = (-B) % bb
-    if pad:
-        # padded replicas have do=0, so they never commit
-        lane_pad = lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
-        t1, t2, valid, md, q1, dl, src, do = map(
-            lane_pad, (t1, t2, valid, md, q1, dl, src, do)
+    with jax.named_scope("placement/layout"):
+        t1, t2, valid, md, q1, dl, src, do = to_lanes(
+            t1, t2, valid, min_dur, q1, dl, src, do
         )
+        valid = valid.astype(jnp.int32)
+        do = do.astype(jnp.int32)
+        if pad:
+            # padded replicas have do=0, so they never commit
+            lane_pad = lambda a: jnp.pad(
+                a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
+            t1, t2, valid, md, q1, dl, src, do = map(
+                lane_pad, (t1, t2, valid, md, q1, dl, src, do)
+            )
     Bp = B + pad
 
     win_spec = pl.BlockSpec(
@@ -127,24 +133,26 @@ def fused_place(t1, t2, valid, min_dur, q1, dl, src, do, *,
     kernel = functools.partial(
         _placement_kernel, cfg_pref=cfg_pref, cfg_fallback=cfg_fallback
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(Bp // bb,),
-        in_specs=[devp_spec, devp_spec, rep_spec, rep_spec, cfgp_spec,
-                  win_spec, win_spec, win_spec],
-        out_specs=[win_spec, win_spec, win_spec, rep_spec, rep_spec,
-                   rep_spec, rep_spec, rep_spec, rep_spec],
-        out_shape=[
-            win(jnp.float32), win(jnp.float32), win(jnp.int32),
-            rep(jnp.int32), rep(jnp.int32), rep(jnp.float32),
-            rep(jnp.float32), rep(jnp.int32), rep(jnp.int32),
-        ],
-        # the commit is an in-place update of the window arrays
-        input_output_aliases={5: 0, 6: 1, 7: 2},
-        interpret=interpret,
-    )(q1, dl, src, do, md, t1, t2, valid)
-    nt1, nt2, nv, ok, sel, start, dur, use4, n_drop = (
-        a[..., :B] for a in out
-    )
-    return from_lanes(nt1, nt2, nv != 0, ok != 0, sel, start, dur,
-                      use4 != 0, n_drop)
+    with jax.named_scope("placement/kernel"):
+        out = pl.pallas_call(
+            kernel,
+            grid=(Bp // bb,),
+            in_specs=[devp_spec, devp_spec, rep_spec, rep_spec, cfgp_spec,
+                      win_spec, win_spec, win_spec],
+            out_specs=[win_spec, win_spec, win_spec, rep_spec, rep_spec,
+                       rep_spec, rep_spec, rep_spec, rep_spec],
+            out_shape=[
+                win(jnp.float32), win(jnp.float32), win(jnp.int32),
+                rep(jnp.int32), rep(jnp.int32), rep(jnp.float32),
+                rep(jnp.float32), rep(jnp.int32), rep(jnp.int32),
+            ],
+            # the commit is an in-place update of the window arrays
+            input_output_aliases={5: 0, 6: 1, 7: 2},
+            interpret=interpret,
+        )(q1, dl, src, do, md, t1, t2, valid)
+    with jax.named_scope("placement/layout"):
+        nt1, nt2, nv, ok, sel, start, dur, use4, n_drop = (
+            a[..., :B] for a in out
+        )
+        return from_lanes(nt1, nt2, nv != 0, ok != 0, sel, start, dur,
+                          use4 != 0, n_drop)

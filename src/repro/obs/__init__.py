@@ -14,7 +14,7 @@ from repro.obs.export import (
     validate_trace,
     write_chrome_trace,
 )
-from repro.obs.profile import PhaseTimer, maybe_jax_trace, span
+from repro.obs.profile import PhaseTimer, count, maybe_jax_trace, span
 from repro.obs.telemetry import (
     TelemetryFrame,
     TelemetryRecord,
@@ -32,6 +32,7 @@ __all__ = [
     "TelemetryRecord",
     "assemble",
     "capture_tick",
+    "count",
     "fleet_trace_events",
     "load_record",
     "load_trace",

@@ -67,3 +67,17 @@ def test_placement_kernel_compiles_for_v5e(one_chip, B):
     calibration batches of 2 and 1 replicas."""
     compiled = jax.jit(fused_place).lower(*_kernel_args(B, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_placement_scopes_on_v5e(one_chip):
+    """Under a profiler the launch is charged to ``placement/kernel`` and
+    the boundary transposes to ``placement/layout``."""
+    import re
+
+    text = jax.jit(fused_place).lower(
+        *_kernel_args(4096, one_chip)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernels and all("/placement/kernel/" in ln for ln in kernels)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    transposes = [n for n in names if n.endswith("/transpose")]
+    assert transposes and all("/placement/layout/" in n for n in transposes)
