@@ -98,16 +98,6 @@ OCC_TABLE = np.minimum(
 ).astype(np.int32)
 
 
-def _csum(x):
-    """Inclusive cumsum over the last axis via a triangular mask — only
-    broadcast/compare/reduce ops, so the same code lowers inside a Pallas
-    kernel body (jnp.cumsum does not)."""
-    n = x.shape[-1]
-    tril = (jnp.arange(n, dtype=jnp.int32)[:, None]
-            <= jnp.arange(n, dtype=jnp.int32)[None, :])   # k <= w
-    return jnp.sum(jnp.where(tril, x[..., :, None], 0), axis=-2)
-
-
 def _iota(shape, axis):
     """int32 iota along ``axis`` of ``shape`` (never the default int, which
     is int64 under JAX_ENABLE_X64)."""
@@ -307,31 +297,49 @@ def fanout_commit_lanes(t1, t2, valid, min_dur, dev, cfg, s, e, do):
 
 
 def compact_tracks(t1, t2, valid, *, eps: float = 1e-6):
-    """Per-track window compaction: sort windows by start and merge
+    """Per-track window compaction: order windows by start and merge
     adjacent/abutting ones (``next.t1 <= prev.t2 + eps``) so remainders
     produced by repeated bisects cannot clog the fixed-W slots.  Disjoint
     windows conserve total availability exactly.  ``[..., W]`` arrays ->
-    ``(t1', t2', valid')``."""
+    ``(t1', t2', valid')``, bit-identical to a stable argsort by
+    ``where(valid, t1, BIG)``, a running max of ``t2`` and a segment
+    cumsum over the sorted windows.
+
+    No sort and no gather (a TPU gathers a W-slot permutation one tiny
+    slice per index): ``before[..., j, i]``, window j precedes window i
+    in that stable order, comes from W x W compares, and each
+    sorted-order quantity is a masked reduce over j; output slot k takes
+    segment k by one-hot select and reduce.  Window i lies on the lanes
+    of every ``[..., W, W]`` intermediate."""
     W = t1.shape[-1]
-    order = jnp.argsort(jnp.where(valid, t1, BIG), axis=-1)
-    t1s = jnp.take_along_axis(t1, order, axis=-1)
-    t2s = jnp.take_along_axis(t2, order, axis=-1)
-    vs = jnp.take_along_axis(valid, order, axis=-1)
-    cmax = jax.lax.cummax(jnp.where(vs, t2s, -BIG), axis=t1.ndim - 1)
-    prev_end = jnp.concatenate(
-        [jnp.full_like(cmax[..., :1], -BIG), cmax[..., :-1]], axis=-1
+    key = jnp.where(valid, t1, BIG)
+    jj, ii = _iota((W, W), 0), _iota((W, W), 1)
+    k_j, k_i = key[..., :, None], key[..., None, :]
+    before = (k_j < k_i) | ((k_j == k_i) & (jj < ii))          # [..., j, i]
+    # end of the windows before i (the sorted cummax, shifted by one);
+    # the same f32 ``+ eps`` as the sorted form, so it rounds alike
+    prev_end = jnp.max(
+        jnp.where(before & valid[..., :, None], t2[..., :, None], -BIG),
+        axis=-2,
     )
-    starts_seg = vs & (t1s > prev_end + eps)
-    seg = _csum(starts_seg.astype(jnp.int32)) - 1
-    lanes = jnp.arange(W, dtype=jnp.int32)
-    member = vs[..., None] & (seg[..., None] == lanes)         # [..., W, W]
-    head = starts_seg[..., None] & (seg[..., None] == lanes)
-    new_valid = jnp.any(member, axis=-2)
+    start = valid & (t1 > prev_end + eps)
+    seg = jnp.sum(
+        ((before | (jj == ii)) & start[..., :, None]).astype(jnp.int32),
+        axis=-2, dtype=jnp.int32,
+    ) - 1
+    slot = seg[..., None, :] == jj                             # [..., k, i]
+    member = valid[..., None, :] & slot
+    new_valid = jnp.any(member, axis=-1)
+    # a segment has one start, so the sum has one non-zero term: exact
     new_t1 = jnp.where(
-        new_valid, jnp.sum(jnp.where(head, t1s[..., None], 0.0), axis=-2), BIG
+        new_valid,
+        jnp.sum(jnp.where(start[..., None, :] & slot, t1[..., None, :], 0.0),
+                axis=-1),
+        BIG,
     )
     new_t2 = jnp.where(
-        new_valid, jnp.max(jnp.where(member, t2s[..., None], -BIG), axis=-2),
+        new_valid,
+        jnp.max(jnp.where(member, t2[..., None, :], -BIG), axis=-1),
         BIG,
     )
     return new_t1, new_t2, new_valid

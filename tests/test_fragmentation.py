@@ -10,12 +10,14 @@ accounting identity:
                            + dropped time + sub-min-duration discards
 
 for arbitrary bisect sequences, and the measure/disjointness invariants
-of the in-scan window compaction pass.
+of the in-scan window compaction pass, which must equal its sort-and-
+gather statement bit for bit and lower with no gather or sort.
 """
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from _hyp import given, settings, st
@@ -23,6 +25,8 @@ from _hyp import given, settings, st
 from repro.core.jax_state import (
     BIG,
     OCC_TABLE,
+    SchedState,
+    compact_state,
     compact_tracks,
     fanout_commit,
 )
@@ -226,3 +230,126 @@ def test_compaction_merges_abutting_windows():
     assert nv[0].sum() == 2
     np.testing.assert_allclose(nt1[0, :2], [0.0, 9.0])
     np.testing.assert_allclose(nt2[0, :2], [7.0, 11.0])
+
+
+def _compact_sorted(t1, t2, valid, *, eps=1e-6):
+    """The sort-and-gather statement of compaction, the oracle the
+    gather-free ``compact_tracks`` must match bit for bit: stable argsort
+    by ``where(valid, t1, BIG)``, the windows permuted with
+    ``take_along_axis``, a running max of ``t2`` and a segment cumsum in
+    that order."""
+    W = t1.shape[-1]
+    order = jnp.argsort(jnp.where(valid, t1, BIG), axis=-1, stable=True)
+    t1s = jnp.take_along_axis(t1, order, axis=-1)
+    t2s = jnp.take_along_axis(t2, order, axis=-1)
+    vs = jnp.take_along_axis(valid, order, axis=-1)
+    cmax = jax.lax.cummax(jnp.where(vs, t2s, -BIG), axis=t1.ndim - 1)
+    prev_end = jnp.concatenate(
+        [jnp.full_like(cmax[..., :1], -BIG), cmax[..., :-1]], axis=-1
+    )
+    starts_seg = vs & (t1s > prev_end + eps)
+    seg = jnp.cumsum(starts_seg.astype(jnp.int32), axis=-1,
+                     dtype=jnp.int32) - 1
+    slots = jnp.arange(W, dtype=jnp.int32)
+    member = vs[..., None] & (seg[..., None] == slots)          # [..., W, W]
+    head = starts_seg[..., None] & (seg[..., None] == slots)
+    new_valid = jnp.any(member, axis=-2)
+    new_t1 = jnp.where(
+        new_valid, jnp.sum(jnp.where(head, t1s[..., None], 0.0), axis=-2), BIG
+    )
+    new_t2 = jnp.where(
+        new_valid, jnp.max(jnp.where(member, t2s[..., None], -BIG), axis=-2),
+        BIG,
+    )
+    return new_t1, new_t2, new_valid
+
+
+_EPS = 1e-6
+#: gaps between consecutive windows of a chain: overlapping, abutting,
+#: within ``eps`` of abutting on either side, and apart
+_GAPS = np.array([-1.0, -0.25, 0.0, _EPS / 2, _EPS, 2 * _EPS, 0.25, 1.0],
+                 np.float32)
+
+
+def _fragmented_tracks(seed, b, w, p_valid):
+    """``[b, DEV, CFG, T, w]`` windows in every kind of track: random
+    starts on a coarse grid (ties), chains whose neighbours overlap, abut
+    or abut within eps, all-valid and all-invalid tracks; invalid slots
+    hold arbitrary values (ties with valid starts, BIG among them).
+    Slots are shuffled, so compaction cannot lean on their order."""
+    rng = np.random.default_rng(seed)
+    shape = (b, DEV, CFG, T, w)
+    t1 = np.empty(shape, np.float32)
+    t2 = np.empty(shape, np.float32)
+    valid = np.empty(shape, bool)
+    for idx in np.ndindex(shape[:-1]):
+        kind = rng.integers(4)
+        if kind == 0:                              # grid starts: ties
+            a = rng.integers(0, 6, w).astype(np.float32) * 0.5
+            # an inverted window (t2 < t1) makes the order among tied
+            # starts visible in the output
+            d = rng.choice(np.float32([-0.5, 0.0, 0.5, 1.0, 2.5]), w)
+        else:                                      # a chain, in order
+            gaps = rng.choice(_GAPS, w)
+            d = rng.choice(np.float32([0.25, 0.5, 1.0, 3.0]), w)
+            a = np.empty(w, np.float32)
+            end = np.float32(rng.integers(0, 4))
+            for k in range(w):
+                a[k] = end + gaps[k]
+                end = np.float32(a[k] + d[k])
+        t1[idx], t2[idx] = a, (a + d).astype(np.float32)
+        v = rng.random(w) < p_valid
+        valid[idx] = (True if kind == 2 else False if kind == 3 else v)
+        perm = rng.permutation(w)
+        t1[idx], t2[idx], valid[idx] = t1[idx][perm], t2[idx][perm], \
+            valid[idx][perm]
+    # invalid slots keep arbitrary leftovers, BIG among them
+    junk = rng.random(shape) < 0.3
+    t1 = np.where(~valid & junk, BIG, t1).astype(np.float32)
+    t2 = np.where(~valid & ~junk & (rng.random(shape) < 0.5),
+                  -t2, t2).astype(np.float32)
+    return t1, t2, valid
+
+
+@given(st.integers(0, 2**32 - 1), st.integers(1, 3), st.sampled_from([8, 16]),
+       st.sampled_from([0.0, 0.3, 0.7, 1.0]))
+@settings(max_examples=40, deadline=None)
+def test_compaction_matches_sorted_oracle_bit_for_bit(seed, b, w, p_valid):
+    """The gather-free compaction equals the argsort + ``take_along_axis``
+    statement exactly (t1, t2 and valid, every slot) on batched states
+    with tied starts, junk in invalid slots, all-valid and all-invalid
+    tracks, and windows that overlap, abut or abut within eps."""
+    t1, t2, valid = _fragmented_tracks(seed, b, w, p_valid)
+    args = (jnp.asarray(t1), jnp.asarray(t2), jnp.asarray(valid))
+    got = jax.jit(compact_tracks)(*args)
+    want = jax.jit(_compact_sorted)(*args)
+    for name, g, x in zip(("t1", "t2", "valid"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x),
+                                      err_msg=name)
+
+
+def test_compaction_lowers_without_gather_sort_or_scatter():
+    """``compact_state`` on a batched ``[8, 4, 3, 2, 16]`` state is
+    compares, selects and reduces only: no ``gather``, ``sort`` or
+    ``scatter`` primitive anywhere in its jaxpr."""
+    shape = (8, 4, 3, 2, 16)
+    win = jax.ShapeDtypeStruct(shape, jnp.float32)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    state = SchedState(
+        win_t1=win, win_t2=win,
+        win_valid=jax.ShapeDtypeStruct(shape, jnp.bool_),
+        min_dur=f32(8, 3), link_t1=f32(8, 4), link_t2=f32(8, 4),
+        link_cap=i32(8, 4), link_used=i32(8, 4),
+    )
+
+    def prims(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from prims(sub)
+
+    names = set(prims(jax.make_jaxpr(compact_state)(state).jaxpr))
+    assert "reduce_max" in names                  # the traversal sees ops
+    assert not {n for n in names
+                if n.startswith(("gather", "sort", "scatter"))}, names
