@@ -145,12 +145,24 @@ class FleetParams:
     telemetry_every: int = 1
 
 
-def _hp_query(st: SchedState, dev: int, now, dur, hp_deadline: float):
-    """HP containment query on one device: a `dur` slot starting in
-    [now, now + hp_deadline - dur] (§IV.B.1)."""
-    t1 = st.win_t1[:, dev, HP_IDX]                    # [B, T, W]
-    t2 = st.win_t2[:, dev, HP_IDX]
-    valid = st.win_valid[:, dev, HP_IDX]
+def _col(x, d):
+    """``x[:, d]`` for a traced device index ``d``."""
+    return jax.lax.dynamic_index_in_dim(x, d, axis=1, keepdims=False,
+                                        allow_negative_indices=False)
+
+
+def _set_col(x, d, col):
+    """``x.at[:, d].set(col)`` for a traced device index ``d``."""
+    return jax.lax.dynamic_update_index_in_dim(x, col, d, axis=1,
+                                               allow_negative_indices=False)
+
+
+def _hp_query(st: SchedState, dev, now, dur, hp_deadline: float):
+    """HP containment query on device ``dev`` (traced): a `dur` slot
+    starting in [now, now + hp_deadline - dur] (§IV.B.1)."""
+    t1 = _col(st.win_t1, dev)[:, HP_IDX]              # [B, T, W]
+    t2 = _col(st.win_t2, dev)[:, HP_IDX]
+    valid = _col(st.win_valid, dev)[:, HP_IDX]
     nowb = now[:, None, None]
     durb = dur[:, None, None]
     deadline = nowb + jnp.maximum(hp_deadline, durb + 1e-6)
@@ -161,9 +173,9 @@ def _hp_query(st: SchedState, dev: int, now, dur, hp_deadline: float):
     return best < BIG, best
 
 
-def _hp_commit(st: SchedState, dev: int, s, e, do):
-    """§IV.A.1 fan-out commit of an HP slot on device `dev`, per replica.
-    Returns (state', n_dropped[B])."""
+def _hp_commit(st: SchedState, dev, s, e, do):
+    """§IV.A.1 fan-out commit of an HP slot on device ``dev`` (traced),
+    per replica.  Returns (state', n_dropped[B])."""
     B = s.shape[0]
     t1, t2, valid, n_drop, _ = fanout_commit(
         st.win_t1, st.win_t2, st.win_valid, st.min_dur,
@@ -221,6 +233,12 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
     n_dev = p.n_devices
     R = p.requeue_slots
     dev_ids = jnp.arange(n_dev, dtype=jnp.int32)
+    # each device's release offset within the frame (the stagger), and
+    # its LP deadline's
+    t_offset = jnp.asarray(
+        [d * (FRAME_PERIOD / n_dev) * p.stagger for d in range(n_dev)],
+        jnp.float32)
+    dl_offset = t_offset + p.lp_deadline_factor * FRAME_PERIOD
     rows = jnp.arange(B, dtype=jnp.int32)
     if sanitize:
         _sanitize.check_sched_state(carry[0], "fleet segment input")
@@ -230,11 +248,9 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
         # tick/requeue, tick/hp, tick/realloc, tick/lp, tick/mask) that
         # partition the body, so a profiler trace charges every op to one
         # phase; each stats update sits in the phase that produced it.
+        # hp, realloc and lp run once per device inside the device loop,
+        # under tick/device, which alone holds the loop's own work.
         st0, link_free0, rq0, vc0, stats0 = carry
-        if p.telemetry:
-            # per-device decision counts for obs/: appended once per
-            # device below, stacked to [B, Dev] at capture time
-            pd_run, pd_fail, pd_preempt, pd_lp = [], [], [], []
         st, link_free, stats = st0, link_free0, stats0
         rq_dl, rq_src, rq_ok = rq0
         vc_s, vc_end, vc_dl, vc_src, vc_ok = vc0
@@ -307,11 +323,21 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                 )
                 rq_ok = rq_ok.at[rows, slot].set(valid_r & ~ok)
 
-        for d in range(n_dev):
+        def device_step(d, dc):
+            # one device's frame release; ``d`` is traced, so every device
+            # runs this one body and the program does not grow with Dev
+            st, link_free, stats, (rq_dl, rq_src, rq_ok), vc, pd = dc
+            vc_s, vc_end, vc_dl, vc_src, vc_ok = vc
             with jax.named_scope("tick/hp"):
-                t_rel = base + d * (FRAME_PERIOD / n_dev) * p.stagger
-                now = jnp.full((B,), 0.0, jnp.float32) + t_rel
-                vd = v[:, d].astype(jnp.int32)
+                # release and LP deadline each as one multiply-add of the
+                # frame index and the device's offset.  The compiler may
+                # fuse it and round once, so the multiply has to stay in
+                # the loop, next to the add: ``d >= 0`` always holds, but
+                # ties it to the loop so it is not hoisted out.
+                fbase = jnp.where(d >= 0, f, 0).astype(jnp.float32) * (
+                    FRAME_PERIOD)
+                now = jnp.full((B,), 0.0, jnp.float32) + (fbase + t_offset[d])
+                vd = _col(v, d)
                 has_frame = vd >= 0
 
                 # -- HP: immediate slot on the source device ---------------
@@ -328,8 +354,8 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                 if R > 0:
                     # the serial engine evicts only a task whose reserved
                     # slot overlaps the requested HP window (§IV.B.3)
-                    victim_live = (vc_ok[:, d] & (vc_end[:, d] > now)
-                                   & (vc_s[:, d] < now + hp_dur))
+                    victim_live = (_col(vc_ok, d) & (_col(vc_end, d) > now)
+                                   & (_col(vc_s, d) < now + hp_dur))
                 else:
                     # reallocation disabled: legacy capacity-eviction
                     # semantics (HP always runs, victims implicitly keep
@@ -351,7 +377,7 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     hp_preempted=stats.hp_preempted + preempt,
                 )
                 if R > 0:
-                    vc_ok = vc_ok.at[:, d].set(vc_ok[:, d] & ~preempt)
+                    vc_ok = _set_col(vc_ok, d, _col(vc_ok, d) & ~preempt)
                     # the victim's placement-time completion credit is
                     # revoked; re-earned on re-placement or it becomes a
                     # miss
@@ -365,8 +391,8 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     # that path succeeds in the common case — deferring a
                     # whole frame period would eat most of the victim's
                     # deadline budget)
-                    dl_v = vc_dl[:, d]
-                    src_v = vc_src[:, d]
+                    dl_v = _col(vc_dl, d)
+                    src_v = _col(vc_src, d)
                     comm_end = jnp.maximum(link_free, now) + ttime
                     q1 = jnp.where(
                         dev_ids[None, :] == src_v[:, None], now[:, None],
@@ -416,7 +442,8 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                 # -- LP: up to 4 DNN tasks once HP completes ---------------
                 n_lp = jnp.where(hp_ok, jnp.clip(vd, 0, MAX_LP), 0)
                 release = hp_start + hp_dur
-                deadline = now + p.lp_deadline_factor * FRAME_PERIOD
+                deadline = jnp.full((B,), 0.0, jnp.float32) + (
+                    fbase + dl_offset[d])
                 frame_ok = hp_ok
                 src_d = jnp.full((B,), d, jnp.int32)
                 if p.telemetry:
@@ -460,10 +487,21 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     + (has_frame & frame_ok)
                 )
             if p.telemetry:
-                pd_run.append(hp_ok)
-                pd_fail.append(hp_fail)
-                pd_preempt.append(preempt)
-                pd_lp.append(lp_placed_d)
+                pd = tuple(
+                    _set_col(a, d, x.astype(jnp.int32)) for a, x in
+                    zip(pd, (hp_ok, hp_fail, preempt, lp_placed_d)))
+            return (st, link_free, stats, (rq_dl, rq_src, rq_ok),
+                    (vc_s, vc_end, vc_dl, vc_src, vc_ok), pd)
+
+        # per-device decision counts for obs/, [B, Dev] each
+        pd0 = ((jnp.zeros((B, n_dev), jnp.int32),) * 4 if p.telemetry
+               else ())
+        with jax.named_scope("tick/device"):
+            st, link_free, stats, (rq_dl, rq_src, rq_ok), vc, pd = (
+                jax.lax.fori_loop(0, n_dev, device_step, (
+                    st, link_free, stats, (rq_dl, rq_src, rq_ok),
+                    (vc_s, vc_end, vc_dl, vc_src, vc_ok), pd0)))
+        vc_s, vc_end, vc_dl, vc_src, vc_ok = vc
         with jax.named_scope("tick/mask"):
             if sanitize:
                 _sanitize.check_windows(
@@ -493,13 +531,9 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
             # read-only capture from the post-mask carry: the per-device
             # decision counts are already zero on padded ticks (padded
             # trace values are -1, so has_frame is False everywhere)
-            def stack_i32(xs_):
-                return jnp.stack(xs_, axis=1).astype(jnp.int32)
-
             ys = _telemetry.capture_tick(
                 out[0], out[1], out[2][2], stats0, out[4], base, bws,
-                p.nominal_bw_bps, stack_i32(pd_run), stack_i32(pd_fail),
-                stack_i32(pd_preempt), jnp.stack(pd_lp, axis=1),
+                p.nominal_bw_bps, *pd,
             )
             return out, ys
 
@@ -581,20 +615,26 @@ def _run_segment_sharded_checked(params: FleetParams):
 def initial_carry(fleet: FleetState, pad_b: int = 0):
     """The segment scan's carry for ``fleet``: its buffers and zero stats,
     with ``pad_b`` padding replicas appended to the batch."""
-    B = fleet.sched.win_t1.shape[0]
-    Bp = B + pad_b
     state_tree = (
         fleet.sched, fleet.link_free,
         (fleet.rq_deadline, fleet.rq_src, fleet.rq_valid),
         (fleet.vc_start, fleet.vc_end, fleet.vc_deadline, fleet.vc_src,
          fleet.vc_valid),
     )
-    # copy the carry: the segment runners donate their input buffers, and
-    # the caller's fleet must stay valid (benchmarks re-run the same
-    # fleet).  The zero stats leaves are copied too — jnp.zeros dedupes
-    # identical constants, and donation rejects aliased buffers.  Batch
-    # padding tiles existing replica rows instead — any valid state
-    # works, the padded columns release no frames.
+    return _initial_carry(state_tree, pad_b=pad_b)
+
+
+@functools.partial(jax.jit, static_argnames="pad_b")
+def _initial_carry(state_tree, *, pad_b):
+    # one device program, so a call costs the host one dispatch.  Copy
+    # the carry: the segment runners donate their input buffers, and the
+    # caller's fleet must stay valid (benchmarks re-run the same fleet);
+    # every output of the program is a buffer of its own, the zero stats
+    # leaves that share a value too, as donation requires.  Batch padding
+    # tiles existing replica rows instead — any valid state works, the
+    # padded columns release no frames.
+    B = state_tree[1].shape[0]
+    Bp = B + pad_b
     if pad_b:
         rows = jnp.arange(Bp, dtype=jnp.int32) % B
         state_tree = jax.tree_util.tree_map(

@@ -39,10 +39,12 @@ Preemption fidelity (§IV.B.3) needs two more groups of arrays:
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.jax_state import BIG as STATE_BIG  # noqa: F401  (re-export)
 from repro.core.jax_state import SchedState, export_state
@@ -78,12 +80,27 @@ def stack_states(states: list[SchedState]) -> SchedState:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
 
 
+@functools.lru_cache(maxsize=None)
+def _pristine_replica(n_devices: int, bandwidth_bps: float,
+                      max_windows: int) -> SchedState:
+    """One replica's pristine SchedState as host arrays, exported from a
+    fresh `RASScheduler` once per layout (eagerly, also when first
+    asked for inside a trace)."""
+    with jax.ensure_compile_time_eval():
+        base = export_state(
+            RASScheduler(n_devices, bandwidth_bps), max_windows=max_windows
+        )
+        return jax.tree_util.tree_map(np.asarray, base)
+
+
 def make_fleet(batch: int, n_devices: int = 4, bandwidth_bps: float = 20e6,
                *, max_windows: int = 16, requeue_slots: int = 4) -> FleetState:
     """A pristine B-replica fleet: every device fully available from t=0.
 
     Built by exporting a fresh `RASScheduler` (so window/track/link layout
-    is byte-identical to the reference path) and broadcasting it.
+    is byte-identical to the reference path) and broadcasting it, in one
+    device program: a sweep builds a fleet per batch, and each eager op
+    would cost the host a dispatch while the device waits.
 
     ``max_windows=16`` (the export default) is calibrated for the fleet
     scan: the per-tick housekeeping pass recycles elapsed windows, so
@@ -94,9 +111,13 @@ def make_fleet(batch: int, n_devices: int = 4, bandwidth_bps: float = 20e6,
     engine that will consume this fleet (the re-queue buffer is part of
     the scan carry, so its width is a compile-time shape).
     """
-    base = export_state(
-        RASScheduler(n_devices, bandwidth_bps), max_windows=max_windows
-    )
+    return _make_fleet(batch, n_devices, float(bandwidth_bps), max_windows,
+                       requeue_slots)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _make_fleet(batch, n_devices, bandwidth_bps, max_windows, requeue_slots):
+    base = _pristine_replica(n_devices, bandwidth_bps, max_windows)
     return FleetState(
         sched=broadcast_state(base, batch),
         link_free=jnp.zeros((batch,), jnp.float32),

@@ -70,4 +70,6 @@ def geometries():
         _case(32, 4, 3, 2, 16, 128),     # B=256 @ 8 shards
         _case(256, 4, 3, 2, 16, 128),    # B=2048 @ 8 shards; B=256 batches
         _case(1024, 4, 3, 2, 16, 128),   # B=4096 @ 4 shards
+        # a 50-device site (bench site50): one 128-replica tile
+        _case(128, 50, 3, 2, 16, 128),
     ]

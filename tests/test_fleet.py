@@ -353,6 +353,45 @@ def test_donated_carry_leaves_input_fleet_valid():
     _assert_runs_equal(first, again)
 
 
+@pytest.mark.parametrize("batch, n_dev, slots", [(8, 4, 4), (3, 50, 2)])
+def test_make_fleet_broadcasts_the_exported_scheduler(batch, n_dev, slots):
+    """One device program builds the fleet: every replica holds the
+    exported pristine scheduler, every other buffer is zero, with the
+    shapes and dtypes the carry expects."""
+    fleet = make_fleet(batch, n_dev, requeue_slots=slots)
+    base = export_state(RASScheduler(n_dev, 20e6))
+    for got, want in zip(fleet.sched, base):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got), np.broadcast_to(np.asarray(want),
+                                             (batch,) + want.shape))
+    shapes = {"link_free": (batch,), "now": (batch,),
+              "rq_deadline": (batch, slots), "rq_src": (batch, slots),
+              "rq_valid": (batch, slots), "vc_start": (batch, n_dev),
+              "vc_end": (batch, n_dev), "vc_deadline": (batch, n_dev),
+              "vc_src": (batch, n_dev), "vc_valid": (batch, n_dev)}
+    for name, shape in shapes.items():
+        x = getattr(fleet, name)
+        assert x.shape == shape and not np.asarray(x).any(), name
+    assert fleet.rq_src.dtype == fleet.vc_src.dtype == jnp.int32
+    assert fleet.rq_valid.dtype == fleet.vc_valid.dtype == jnp.bool_
+
+
+def test_initial_carry_gives_every_leaf_a_buffer_of_its_own():
+    """The segment runners donate the carry, so each leaf needs its own
+    buffer, shared neither with the caller's fleet nor with another leaf
+    (the zero stats all hold one value)."""
+    from repro.fleet import engine
+
+    fleet = make_fleet(B, DEV)
+    carry = engine.initial_carry(fleet)
+    ptrs = [x.unsafe_buffer_pointer() for x in jax.tree_util.tree_leaves(carry)]
+    theirs = {x.unsafe_buffer_pointer()
+              for x in jax.tree_util.tree_leaves(fleet)}
+    assert len(set(ptrs)) == len(ptrs)
+    assert not set(ptrs) & theirs
+
+
 def test_per_tick_compaction_preserves_invariants():
     """compact_every=1 (a compaction pass before every tick) must keep
     the conservation identities intact and never decrease completions —

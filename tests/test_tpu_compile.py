@@ -50,23 +50,58 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _kernel_args(B, sharding):
+def _kernel_args(B, sharding, dev=DEV):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    win = (B, DEV, CFG, T, W)
+    win = (B, dev, CFG, T, W)
     return (s(win, jnp.float32), s(win, jnp.float32), s(win, jnp.bool_),
-            s((B, CFG), jnp.float32), s((B, DEV), jnp.float32),
-            s((B, DEV), jnp.float32), s((B,), jnp.int32), s((B,), jnp.bool_))
+            s((B, CFG), jnp.float32), s((B, dev), jnp.float32),
+            s((B, dev), jnp.float32), s((B,), jnp.int32), s((B,), jnp.bool_))
 
 
-@pytest.mark.parametrize("B", [4096, 1024, 2, 1],
+@pytest.mark.parametrize("B, dev", [(4096, DEV), (1024, DEV), (2, DEV),
+                                    (1, DEV), (128, 50)],
                          ids=["sweep_batch", "mesh_shard", "calib_quick",
-                              "calib_b1"])
-def test_placement_kernel_compiles_for_v5e(one_chip, B):
+                              "calib_b1", "site50"])
+def test_placement_kernel_compiles_for_v5e(one_chip, B, dev):
     """The kernel at the fleet's local batch sizes: a 4,096-replica sweep
-    batch on one chip, its 1,024-replica shard on a 4-chip mesh, and the
-    calibration batches of 2 and 1 replicas."""
-    compiled = jax.jit(fused_place).lower(*_kernel_args(B, one_chip)).compile()
+    batch on one chip, its 1,024-replica shard on a 4-chip mesh, the
+    calibration batches of 2 and 1 replicas, and one 128-replica tile of
+    a 50-device site (its blocks grow with the device count)."""
+    compiled = jax.jit(fused_place).lower(
+        *_kernel_args(B, one_chip, dev)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_dev", [4, 50], ids=["paper_site", "site50"])
+def test_segment_program_compiles_for_v5e(one_chip, monkeypatch, n_dev):
+    """The whole 40-tick segment program with the compiled kernel, at 128
+    replicas, for the paper's 4 devices and a 50-device site.  The device
+    loop writes each placement launch once, so both hold 6 kernel calls.
+    The compile time is printed, for the record, and not asserted."""
+    import time
+
+    from repro.fleet import FleetParams, engine, make_fleet
+    from repro.kernels.placement import ops
+
+    # on a CPU host "kernel" means interpret mode; compile the kernel
+    monkeypatch.setattr(ops, "resolve_backend", lambda backend: (True, False))
+    B, S = 128, 40
+    params = FleetParams(n_devices=n_dev, placement_backend="kernel")
+    shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    carry = jax.tree_util.tree_map(
+        shape, engine.initial_carry(make_fleet(B, n_dev)))
+    args = (carry, shape(jnp.zeros((S, B, n_dev), jnp.int32)),
+            shape(jnp.zeros((S, B), jnp.float32)),
+            shape(jnp.int32(0)), shape(jnp.int32(S)))
+    t = time.perf_counter()
+    compiled = engine._run_segment.lower(*args, params=params).compile()
+    print(f"v5e segment compile, {n_dev} devices: "
+          f"{time.perf_counter() - t:.1f} s")
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 + 1 + engine.MAX_LP
 
 
 def test_placement_scopes_on_v5e(one_chip):
