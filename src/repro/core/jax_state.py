@@ -225,6 +225,29 @@ def _commit_row(t1d, t2d, vd, md, cfg, s, e, do):
     return nt1, nt2, nv, n_drop, t_drop
 
 
+def commit_device_rows(t1d, t2d, vd, min_dur, cfg, s, e, do):
+    """§IV.A.1 fan-out trim of one device's rows, one row per replica:
+    consume ``[s, e)`` from the ``OCC_TABLE[cfg, ci]`` most-overlapping
+    tracks of every config list ``ci`` (multi-remainder).
+
+    Shapes: rows ``[N, CFG, T, W]``; ``min_dur [N, CFG]``; ``cfg`` i32,
+    ``s``/``e`` f32 and ``do`` bool ``[N]``.  Returns ``(t1', t2',
+    valid', n_dropped [N], time_dropped [N])``: rows where ``do`` is
+    False come back bit-identical to the input.  The trim is
+    ``_commit_row`` on the rows turned replica-last, the same trace the
+    Pallas placement kernel commits with (``fanout_commit_lanes``).
+    """
+    lanes = lambda a: jnp.moveaxis(a, 0, -1)
+    back = lambda a: jnp.moveaxis(a, -1, 0)
+    nt1, nt2, nv, n_drop, t_drop = _commit_row(
+        lanes(t1d), lanes(t2d), lanes(vd), min_dur.T[:, None], cfg[None],
+        s[None], e[None], do[None],
+    )
+    dom = do[:, None, None, None]
+    return (jnp.where(dom, back(nt1), t1d), jnp.where(dom, back(nt2), t2d),
+            jnp.where(dom, back(nv), vd), n_drop[0], t_drop[0])
+
+
 def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
                   sanitize: bool = False):
     """Batched §IV.A.1 fan-out commit: consume ``[s, e)`` on device
@@ -236,30 +259,26 @@ def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
     ``[N]`` masks the commit per row.  Returns
     ``(t1', t2', valid', n_dropped [N], time_dropped [N])``.
 
-    The committed device row is gathered with ``take_along_axis`` and
-    written back with an ``.at[].set`` scatter, so XLA updates that row
-    in place inside the fleet scan instead of rewriting the whole
-    ``[N, Dev, CFG, T, W]`` state per commit.  The trim itself is
-    ``_commit_row`` on the row turned replica-last, the same trace the
-    Pallas placement kernel commits with (``fanout_commit_lanes``).
+    Each row may commit on its own device, so the row is gathered with
+    ``take_along_axis``, trimmed by ``commit_device_rows`` and written
+    back with an ``.at[].set`` scatter.  On a TPU that write is no
+    in-place row update: XLA lowers it as a scatter over a flattened
+    ``[N x Dev, CFG, T, W]`` view and relays out the whole state to
+    reach it.  The fleet engine commits on one device index shared by
+    the batch and uses ``commit_device_rows`` on a dynamic slice
+    instead; this form's callers are ``hp_place``/``lp_place`` and
+    tests.
     """
     N = t1.shape[0]
     idx = dev[:, None, None, None, None]
     take = lambda a: jnp.take_along_axis(a, idx, axis=1)[:, 0]
-    t1d, t2d, vd = take(t1), take(t2), take(valid)             # [N, CFG, T, W]
-    lanes = lambda a: jnp.moveaxis(a, 0, -1)
-    back = lambda a: jnp.moveaxis(a, -1, 0)
-    nt1, nt2, nv, n_drop, t_drop = _commit_row(
-        lanes(t1d), lanes(t2d), lanes(vd), min_dur.T[:, None], cfg[None],
-        s[None], e[None], do[None],
+    nt1, nt2, nv, n_drop, t_drop = commit_device_rows(
+        take(t1), take(t2), take(valid), min_dur, cfg, s, e, do
     )
-    # write back only committed rows (do=False rows stay bit-identical)
     rows = jnp.arange(N, dtype=jnp.int32)
-    dom = do[:, None, None, None]
-    out_t1 = t1.at[rows, dev].set(jnp.where(dom, back(nt1), t1d))
-    out_t2 = t2.at[rows, dev].set(jnp.where(dom, back(nt2), t2d))
-    out_valid = valid.at[rows, dev].set(jnp.where(dom, back(nv), vd))
-    n_drop, t_drop = n_drop[0], t_drop[0]
+    out_t1 = t1.at[rows, dev].set(nt1)
+    out_t2 = t2.at[rows, dev].set(nt2)
+    out_valid = valid.at[rows, dev].set(nv)
     if sanitize:
         # checkify invariants (only valid under a checkify.checkify
         # transform; checks cannot lower inside a Pallas kernel body)

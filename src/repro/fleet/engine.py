@@ -88,7 +88,7 @@ from jax.sharding import PartitionSpec
 from repro.analysis import sanitize as _sanitize
 from repro.fleet import mesh as _mesh
 from repro.core.jax_state import (
-    BIG, SchedState, compact_state, fanout_commit,
+    BIG, SchedState, commit_device_rows, compact_state,
 )
 from repro.core.tasks import FRAME_PERIOD, MAX_IMAGE_BYTES
 from repro.fleet.metrics import FleetStats, init_stats
@@ -175,14 +175,18 @@ def _hp_query(st: SchedState, dev, now, dur, hp_deadline: float):
 
 def _hp_commit(st: SchedState, dev, s, e, do):
     """§IV.A.1 fan-out commit of an HP slot on device ``dev`` (traced),
-    per replica.  Returns (state', n_dropped[B])."""
-    B = s.shape[0]
-    t1, t2, valid, n_drop, _ = fanout_commit(
-        st.win_t1, st.win_t2, st.win_valid, st.min_dur,
-        jnp.full((B,), dev, jnp.int32), jnp.full((B,), HP_IDX, jnp.int32),
-        s, e, do,
+    per replica.  Every replica commits on the same device, so its row
+    is one dynamic slice of the state, trimmed and written back in
+    place.  Returns (state', n_dropped[B])."""
+    t1, t2, valid, n_drop, _ = commit_device_rows(
+        _col(st.win_t1, dev), _col(st.win_t2, dev), _col(st.win_valid, dev),
+        st.min_dur, jnp.full(s.shape, HP_IDX, jnp.int32), s, e, do,
     )
-    return st._replace(win_t1=t1, win_t2=t2, win_valid=valid), n_drop
+    return st._replace(
+        win_t1=_set_col(st.win_t1, dev, t1),
+        win_t2=_set_col(st.win_t2, dev, t2),
+        win_valid=_set_col(st.win_valid, dev, valid),
+    ), n_drop
 
 
 def _place_lp(st: SchedState, q1, dl, src, do, p: FleetParams):

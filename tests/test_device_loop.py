@@ -8,7 +8,9 @@ does not depend on the device count.
   telemetry, with telemetry on and off, on the mesh and off it, and
   under the sanitizer;
 - the lowered segment program holds as many placement call sites at 50
-  devices as at 4.
+  devices as at 4;
+- the HP commit trims the device's row on a dynamic slice and writes it
+  back in place, bit-identical to the gather/scatter ``fanout_commit``.
 """
 
 import os
@@ -20,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core.jax_state import fanout_commit
 from repro.fleet import FleetParams, fleet_run, make_fleet, make_workload
 from repro.fleet import engine
 
@@ -102,3 +105,81 @@ def test_segment_program_does_not_grow_with_the_device_count():
     # placements, each written once in the device loop's body
     assert four == 1 + 1 + engine.MAX_LP
     assert _placement_call_sites(50) == four
+
+
+def _random_commit(n_dev: int, mask: str, n: int = 16, seed: int = 7):
+    """A fleet of ``n`` replicas with random windows (invalid slots hold
+    junk, not ``BIG``) and one random HP commit per replica: ``(st, s,
+    e, do)``, ``do`` mixed, all True or all False."""
+    rng = np.random.default_rng(seed + n_dev)
+    st = make_fleet(n, n_dev).sched
+    shape = st.win_t1.shape                        # [N, Dev, CFG, T, W]
+    # disjoint windows per track: sorted cut points, paired up
+    cuts = np.sort(rng.uniform(0.0, 100.0, shape[:-1] + (2 * shape[-1],)),
+                   axis=-1).astype(np.float32)
+    t1, t2 = cuts[..., 0::2], cuts[..., 1::2]
+    valid = rng.random(shape) < 0.7
+    t1 = np.where(valid, t1, rng.uniform(-50.0, 150.0, shape))
+    t2 = np.where(valid, t2, rng.uniform(-50.0, 150.0, shape))
+    md = rng.uniform(0.05, 2.0, st.min_dur.shape)
+    s = rng.uniform(0.0, 90.0, n).astype(np.float32)
+    e = s + rng.uniform(0.5, 20.0, n).astype(np.float32)
+    do = {"mixed": rng.random(n) < 0.5, "all": np.ones(n, bool),
+          "none": np.zeros(n, bool)}[mask]
+    st = st._replace(win_t1=jnp.asarray(t1, jnp.float32),
+                     win_t2=jnp.asarray(t2, jnp.float32),
+                     win_valid=jnp.asarray(valid),
+                     min_dur=jnp.asarray(md, jnp.float32))
+    return st, jnp.asarray(s), jnp.asarray(e), jnp.asarray(do)
+
+
+@pytest.mark.parametrize("mask", ["mixed", "all", "none"])
+@pytest.mark.parametrize("n_dev, d", [(4, 0), (4, 2), (4, 3),
+                                      (50, 0), (50, 25), (50, 49)])
+def test_hp_commit_matches_fanout_commit_bit_for_bit(n_dev, d, mask):
+    st, s, e, do = _random_commit(n_dev, mask)
+    n = s.shape[0]
+    got_st, got_nd = jax.jit(engine._hp_commit)(st, jnp.int32(d), s, e, do)
+    t1, t2, valid, want_nd, _ = jax.jit(fanout_commit)(
+        st.win_t1, st.win_t2, st.win_valid, st.min_dur,
+        jnp.full((n,), d, jnp.int32),
+        jnp.full((n,), engine.HP_IDX, jnp.int32), s, e, do)
+    for name, want in (("win_t1", t1), ("win_t2", t2), ("win_valid", valid)):
+        np.testing.assert_array_equal(np.asarray(getattr(got_st, name)),
+                                      np.asarray(want), err_msg=name)
+    np.testing.assert_array_equal(np.asarray(got_nd), np.asarray(want_nd))
+    changed = np.asarray(got_st.win_t1 != st.win_t1).any(axis=(2, 3, 4))
+    # only device d's row of a committing replica may change, and the
+    # random commits do trim something
+    assert not changed[:, np.arange(n_dev) != d].any()
+    assert not changed[~np.asarray(do)].any()
+    if mask != "none":
+        assert changed[:, d].any()
+
+
+def _primitives(jaxpr) -> set[str]:
+    """Every primitive name in ``jaxpr`` and the jaxprs nested in it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def test_hp_commit_lowers_without_gather_or_scatter():
+    st, s, e, do = _random_commit(4, "mixed", n=8)
+    prims = _primitives(jax.make_jaxpr(engine._hp_commit)(
+        st, jnp.int32(1), s, e, do).jaxpr)
+    assert not {p for p in prims if "gather" in p or "scatter" in p}, prims
+    assert {"dynamic_slice", "dynamic_update_slice"} <= prims
+    # the gather/scatter form it replaced, for contrast
+    n = s.shape[0]
+    old = _primitives(jax.make_jaxpr(fanout_commit)(
+        st.win_t1, st.win_t2, st.win_valid, st.min_dur,
+        jnp.full((n,), 1, jnp.int32), jnp.zeros((n,), jnp.int32),
+        s, e, do).jaxpr)
+    assert {"gather", "scatter"} <= old

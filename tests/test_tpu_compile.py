@@ -72,36 +72,71 @@ def test_placement_kernel_compiles_for_v5e(one_chip, B, dev):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n_dev", [4, 50], ids=["paper_site", "site50"])
-def test_segment_program_compiles_for_v5e(one_chip, monkeypatch, n_dev):
-    """The whole 40-tick segment program with the compiled kernel, at 128
-    replicas, for the paper's 4 devices and a 50-device site.  The device
-    loop writes each placement launch once, so both hold 6 kernel calls.
-    The compile time is printed, for the record, and not asserted."""
+@pytest.fixture(scope="module")
+def segment_hlo(one_chip):
+    """``segment_hlo(B, n_dev)``: the compiled v5e HLO text of the whole
+    40-tick segment program with the compiled kernel, compiled once per
+    shape for the module.  The compile time is printed, for the record,
+    and not asserted."""
     import time
 
     from repro.fleet import FleetParams, engine, make_fleet
     from repro.kernels.placement import ops
 
-    # on a CPU host "kernel" means interpret mode; compile the kernel
-    monkeypatch.setattr(ops, "resolve_backend", lambda backend: (True, False))
-    B, S = 128, 40
-    params = FleetParams(n_devices=n_dev, placement_backend="kernel")
-    shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip)
-    carry = jax.tree_util.tree_map(
-        shape, engine.initial_carry(make_fleet(B, n_dev)))
-    args = (carry, shape(jnp.zeros((S, B, n_dev), jnp.int32)),
-            shape(jnp.zeros((S, B), jnp.float32)),
-            shape(jnp.int32(0)), shape(jnp.int32(S)))
-    t = time.perf_counter()
-    compiled = engine._run_segment.lower(*args, params=params).compile()
-    print(f"v5e segment compile, {n_dev} devices: "
-          f"{time.perf_counter() - t:.1f} s")
-    text = compiled.as_text()
-    calls = [ln for ln in text.splitlines()
+    texts = {}
+
+    def compile_segment(B, n_dev):
+        if (B, n_dev) in texts:
+            return texts[B, n_dev]
+        S = 40
+        params = FleetParams(n_devices=n_dev, placement_backend="kernel")
+        shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=one_chip)
+        carry = jax.tree_util.tree_map(
+            shape, engine.initial_carry(make_fleet(B, n_dev)))
+        args = (carry, shape(jnp.zeros((S, B, n_dev), jnp.int32)),
+                shape(jnp.zeros((S, B), jnp.float32)),
+                shape(jnp.int32(0)), shape(jnp.int32(S)))
+        # on a CPU host "kernel" means interpret mode; compile the kernel
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(ops, "resolve_backend", lambda backend: (True, False))
+            t = time.perf_counter()
+            compiled = engine._run_segment.lower(*args, params=params).compile()
+        print(f"v5e segment compile, B={B}, {n_dev} devices: "
+              f"{time.perf_counter() - t:.1f} s")
+        texts[B, n_dev] = compiled.as_text()
+        return texts[B, n_dev]
+
+    return compile_segment
+
+
+@pytest.mark.parametrize("n_dev", [4, 50], ids=["paper_site", "site50"])
+def test_segment_program_compiles_for_v5e(segment_hlo, n_dev):
+    """The whole 40-tick segment program with the compiled kernel, at 128
+    replicas, for the paper's 4 devices and a 50-device site.  The device
+    loop writes each placement launch once, so both hold 6 kernel calls."""
+    from repro.fleet import engine
+
+    calls = [ln for ln in segment_hlo(128, n_dev).splitlines()
              if "tpu_custom_call" in ln and " custom-call(" in ln]
     assert len(calls) == 1 + 1 + engine.MAX_LP
+
+
+@pytest.mark.parametrize("B, n_dev", [(4096, DEV), (128, 50)],
+                         ids=["paper_site", "site50"])
+def test_hp_commit_has_no_window_scatter_or_flat_relayout_on_v5e(
+        segment_hlo, B, n_dev):
+    """The HP commit writes one device's row back in place: the segment
+    program holds no scatter into the windows and no copy to a flattened
+    ``[B x Dev, CFG, T, W]`` view of them, which is how a per-replica
+    row scatter lowers on a v5e (a relayout of the whole state)."""
+    lines = segment_hlo(B, n_dev).splitlines()
+    win = f",{CFG},{T},{W}]"
+    assert not [ln for ln in lines
+                if " scatter(" in ln and win in ln.split(" scatter(")[0]]
+    flat = f"[{B * n_dev},{CFG},{T},{W}]"
+    assert not [ln for ln in lines
+                if " copy(" in ln and flat in ln.split(" copy(")[0]]
 
 
 def test_placement_scopes_on_v5e(one_chip):
