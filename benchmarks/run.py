@@ -50,11 +50,6 @@ def _congestion(a):
     return bench_congestion.run(n_frames=a.n_frames, seeds=a.seeds)
 
 
-def _query(a):
-    from benchmarks import bench_query
-    return bench_query.run() or {}
-
-
 def _fleet(a):
     from benchmarks import bench_fleet
     return bench_fleet.run(quick=a.quick)
@@ -90,8 +85,6 @@ REGISTRY: tuple[BenchSpec, ...] = (
               _bw_interval),
     BenchSpec("congestion", "Fig. 8 completion under §VI.C link congestion",
               _congestion),
-    BenchSpec("query", "Pallas window-query kernel vs jnp oracle microbench",
-              _query),
     BenchSpec("fleet", "batched fleet engine replicas/sec vs serial DES",
               _fleet),
     BenchSpec("calib", "fleet-vs-serial calibration deltas + tolerance gate",

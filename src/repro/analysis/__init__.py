@@ -13,8 +13,9 @@ analysis & sanitizers"):
   arithmetic, jitted ``lax.scan`` entry points without donated carries,
   and ``pallas_call`` sites not registered with the geometry checker.
 - ``sanitize`` — ``jax.experimental.checkify`` runtime invariants on the
-  §IV.A/§IV.B state machine (window monotonicity, availability
-  conservation, link capacities), switched on with ``REPRO_SANITIZE=1``.
+  fleet scan's §IV.A/§IV.B state machine (window monotonicity,
+  availability conservation, link head and victim-cache order), switched
+  on with ``REPRO_SANITIZE=1``.
 
 Entry points: ``python -m repro.analysis`` or
 ``python -m benchmarks.run --only analysis``.

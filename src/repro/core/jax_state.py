@@ -1,34 +1,28 @@
-"""Fully-jitted scheduler state: the paper's §IV data structures as JAX
-arrays, with allocation steps that run as single XLA programs.
+"""The scheduler state of §IV as JAX arrays, and the jitted commit and
+compaction steps the fleet engine runs on it.
 
-This substantiates DESIGN.md §3: on a TPU-hosted controller the whole
-scheduling decision — multi-containment query across every worker, slot
-selection, window bisection and link reservation — is one fused device
-program (`hp_place` / `lp_place` below), with *no host round-trips*.
-The Python structures in `windows.py` / `netlink.py` remain the reference;
-`export_state` converts a live RASScheduler and the equivalence tests in
-tests/test_jax_state.py pin the two implementations together.
-
-State layout (one pytree of arrays, a valid jit carry):
+``export_state`` converts a live serial ``RASScheduler`` (the reference,
+core/windows.py) into one pytree of arrays, a valid jit carry:
 
     win_t1, win_t2      f32[DEV, CFG, T, W]   availability windows
     win_valid           bool[DEV, CFG, T, W]
     min_dur             f32[CFG]              per-config minimum duration
-    link_t1, link_t2    f32[B]                discretised link buckets
-    link_cap, link_used i32[B]
+
+The §IV.A.1 fan-out commit is one trace, ``_commit_row``, in two
+layouts: ``commit_device_rows`` (one device's rows, the fleet engine's
+HP commit) and ``fanout_commit_lanes`` (replica axis last, the placement
+kernel and its oracle).  ``fanout_commit`` is the per-row reference the
+tests pin both against.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import checkify
 
-from repro.analysis import sanitize as _sanitize
 from repro.core.tasks import ALL_CONFIGS, DEVICE_CORES
 
 BIG = 1e30
@@ -39,10 +33,6 @@ class SchedState(NamedTuple):
     win_t2: jnp.ndarray
     win_valid: jnp.ndarray
     min_dur: jnp.ndarray    # [CFG]
-    link_t1: jnp.ndarray    # [B]
-    link_t2: jnp.ndarray
-    link_cap: jnp.ndarray
-    link_used: jnp.ndarray
 
 
 CFG_INDEX = {c.name: i for i, c in enumerate(ALL_CONFIGS)}
@@ -66,16 +56,11 @@ def export_state(sched, max_windows: int = 16) -> SchedState:
                     t1[d, ci, ti, wi] = w.t1
                     t2[d, ci, ti, wi] = min(w.t2, BIG)
                     valid[d, ci, ti, wi] = True
-    link = sched.link
     return SchedState(
         win_t1=jnp.asarray(t1),
         win_t2=jnp.asarray(t2),
         win_valid=jnp.asarray(valid),
         min_dur=jnp.asarray([c.padded_time for c in ALL_CONFIGS], jnp.float32),
-        link_t1=jnp.asarray([b.t1 for b in link.buckets], jnp.float32),
-        link_t2=jnp.asarray([b.t2 for b in link.buckets], jnp.float32),
-        link_cap=jnp.asarray([b.capacity for b in link.buckets], jnp.int32),
-        link_used=jnp.asarray([len(b.items) for b in link.buckets], jnp.int32),
     )
 
 
@@ -248,8 +233,7 @@ def commit_device_rows(t1d, t2d, vd, min_dur, cfg, s, e, do):
             jnp.where(dom, back(nv), vd), n_drop[0], t_drop[0])
 
 
-def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
-                  sanitize: bool = False):
+def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do):
     """Batched §IV.A.1 fan-out commit: consume ``[s, e)`` on device
     ``dev`` across every config list, trimming the ``OCC_TABLE[cfg, ci]``
     most-overlapping tracks of each list ``ci`` (multi-remainder).
@@ -266,8 +250,8 @@ def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
     ``[N x Dev, CFG, T, W]`` view and relays out the whole state to
     reach it.  The fleet engine commits on one device index shared by
     the batch and uses ``commit_device_rows`` on a dynamic slice
-    instead; this form's callers are ``hp_place``/``lp_place`` and
-    tests.
+    instead; this form is the per-row reference the tests compare the
+    engine's commit with.
     """
     N = t1.shape[0]
     idx = dev[:, None, None, None, None]
@@ -276,21 +260,8 @@ def fanout_commit(t1, t2, valid, min_dur, dev, cfg, s, e, do, *,
         take(t1), take(t2), take(valid), min_dur, cfg, s, e, do
     )
     rows = jnp.arange(N, dtype=jnp.int32)
-    out_t1 = t1.at[rows, dev].set(nt1)
-    out_t2 = t2.at[rows, dev].set(nt2)
-    out_valid = valid.at[rows, dev].set(nv)
-    if sanitize:
-        # checkify invariants (only valid under a checkify.checkify
-        # transform; checks cannot lower inside a Pallas kernel body)
-        _sanitize.check_windows(out_t1, out_t2, out_valid, "fanout_commit")
-        _sanitize.check_no_avail_increase(
-            _sanitize.total_availability(t1, t2, valid, batch_axes=1),
-            _sanitize.total_availability(
-                out_t1, out_t2, out_valid, batch_axes=1
-            ),
-            "fanout_commit",
-        )
-    return out_t1, out_t2, out_valid, n_drop, t_drop
+    return (t1.at[rows, dev].set(nt1), t2.at[rows, dev].set(nt2),
+            valid.at[rows, dev].set(nv), n_drop, t_drop)
 
 
 def fanout_commit_lanes(t1, t2, valid, min_dur, dev, cfg, s, e, do):
@@ -371,196 +342,3 @@ def compact_state(state: SchedState) -> SchedState:
         state.win_t1, state.win_t2, state.win_valid
     )
     return state._replace(win_t1=t1, win_t2=t2, win_valid=valid)
-
-
-# ---------------------------------------------------------------------------
-# queries (pure functions of SchedState)
-# ---------------------------------------------------------------------------
-
-def _device_slot(state: SchedState, dev, cfg_idx, q1, deadline, dur):
-    """Earliest feasible (track, window, start) on one device+config."""
-    t1 = state.win_t1[dev, cfg_idx]          # [T, W]
-    t2 = state.win_t2[dev, cfg_idx]
-    valid = state.win_valid[dev, cfg_idx]
-    start = jnp.maximum(t1, q1)
-    feasible = valid & (start + dur <= jnp.minimum(t2, deadline))
-    key = jnp.where(feasible, start, BIG)
-    flat = jnp.argmin(key.reshape(-1))
-    best = key.reshape(-1)[flat]
-    T, W = t1.shape
-    return best < BIG, flat // W, flat % W, best
-
-
-def _bisect(state: SchedState, dev, cfg_idx, track, slot, s, e,
-            do=True, *, sanitize: bool = False
-            ) -> tuple[SchedState, jnp.ndarray]:
-    """Consume [s, e) from device ``dev`` across EVERY config list (the
-    §IV.A.1 fan-out write) for a committed task of config ``cfg_idx``,
-    keeping ALL min-duration remainders (multi-remainder form — the exact
-    semantics of ``AvailabilityList.subtract``, including the
-    ``OCC_TABLE`` track fan-out for wide tasks).  ``track``/``slot`` are
-    retained for API compatibility; the fan-out recomputes the
-    most-overlapping tracks per config.  ``do`` masks the commit.
-
-    Returns ``(new_state, n_dropped)`` where ``n_dropped`` counts
-    min-duration-satisfying remainders that found no free window slot
-    (fragmentation telemetry — previously a silent drop)."""
-    del track, slot
-    t1, t2, valid, n_drop, _ = fanout_commit(
-        state.win_t1[None], state.win_t2[None], state.win_valid[None],
-        state.min_dur[None],
-        jnp.asarray(dev, jnp.int32)[None],
-        jnp.asarray(cfg_idx, jnp.int32)[None],
-        jnp.asarray(s, jnp.float32)[None],
-        jnp.asarray(e, jnp.float32)[None],
-        jnp.asarray(do, bool)[None],
-        sanitize=sanitize,
-    )
-    return state._replace(
-        win_t1=t1[0], win_t2=t2[0], win_valid=valid[0]
-    ), n_drop[0]
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_idx", "sanitize"))
-def hp_place_jit(state: SchedState, dev, now, *, cfg_idx: int = 0,
-                 sanitize: bool = False):
-    """High-priority placement (§IV.B.1): strict containment of
-    [now, now+dur) on the source device, committed in one XLA program.
-    ``sanitize=True`` traces the checkify invariants into the program
-    (only valid under a ``checkify.checkify`` transform); the default
-    trace carries no checks and stays byte-identical to the old build."""
-    if sanitize:
-        _sanitize.check_sched_state(state, "hp_place input")
-        before = _sanitize.total_availability(
-            state.win_t1, state.win_t2, state.win_valid
-        )
-    dur = state.min_dur[cfg_idx]
-    found, track, slot, start = _device_slot(
-        state, dev, cfg_idx, now, now + dur + 1e-6, dur
-    )
-    new_state, _ = _bisect(
-        state, dev, cfg_idx, track, slot, start, start + dur, do=found,
-        sanitize=sanitize,
-    )
-    if sanitize:
-        _sanitize.check_sched_state(new_state, "hp_place output")
-        _sanitize.check_no_avail_increase(
-            before,
-            _sanitize.total_availability(
-                new_state.win_t1, new_state.win_t2, new_state.win_valid
-            ),
-            "hp_place",
-        )
-    return found, start, new_state
-
-
-@functools.lru_cache(maxsize=None)
-def _hp_place_checked(cfg_idx: int):
-    fn = functools.partial(hp_place_jit, cfg_idx=cfg_idx, sanitize=True)
-    return checkify.checkify(fn, errors=checkify.user_checks)
-
-
-def hp_place(state: SchedState, dev, now, *, cfg_idx: int = 0):
-    """Public HP placement: dispatches to the checkify-sanitized variant
-    when ``REPRO_SANITIZE=1`` (repro.analysis.sanitize), raising
-    ``checkify.JaxRuntimeError`` on an invariant trip; otherwise runs the
-    check-free jitted program (``hp_place_jit``)."""
-    if _sanitize.enabled():
-        err, out = _hp_place_checked(cfg_idx)(state, dev, now)
-        err.throw()
-        return out
-    return hp_place_jit(state, dev, now, cfg_idx=cfg_idx)
-
-
-# Donation is deliberately withheld: callers (calib harness, fleet replay)
-# reuse the input SchedState after the call, so donating the carry would
-# invalidate buffers they still hold.
-@functools.partial(jax.jit, static_argnames=("cfg_idx", "n_tasks", "sanitize"))
-def lp_place_jit(state: SchedState, src_dev, now, deadline, *,  # repro: lint-ok(scan-donate)
-                 cfg_idx: int = 1, n_tasks: int = 1,
-                 sanitize: bool = False):
-    """Low-priority request (§IV.B.2): reserve a link slot per task, run the
-    multi-containment query across all devices, prefer the source device,
-    commit each placement — all inside one jitted scan.  ``sanitize=True``
-    traces the checkify invariants (only valid under a
-    ``checkify.checkify`` transform)."""
-    if sanitize:
-        _sanitize.check_sched_state(state, "lp_place input")
-        before = _sanitize.total_availability(
-            state.win_t1, state.win_t2, state.win_valid
-        )
-    dur = state.min_dur[cfg_idx]
-    n_dev = state.win_t1.shape[0]
-
-    def link_reserve(st: SchedState, t_p):
-        ok = (st.link_used < st.link_cap) & (st.link_t2 > t_p)
-        idx = jnp.argmax(ok)
-        found = ok.any()
-        used = st.link_used.at[idx].add(jnp.where(found, 1, 0))
-        return st._replace(link_used=used), found, st.link_t2[idx]
-
-    def place_one(carry, _):
-        st, n_ok = carry
-        st, comm_ok, comm_end = link_reserve(st, now)
-        # multi-containment across every device
-        founds, tracks, slots, starts = jax.vmap(
-            lambda d: _device_slot(st, d, cfg_idx, now, deadline, dur)
-        )(jnp.arange(n_dev, dtype=jnp.int32))
-        # remote devices cannot start before their transfer lands
-        starts_adj = jnp.where(
-            jnp.arange(n_dev, dtype=jnp.int32) == src_dev,
-            starts, jnp.maximum(starts, comm_end)
-        )
-        feasible = founds & (starts_adj + dur <= deadline)
-        feasible &= (jnp.arange(n_dev, dtype=jnp.int32) == src_dev) | comm_ok
-        # prefer source device, then earliest start
-        key = jnp.where(feasible, starts_adj, BIG)
-        key = key - jnp.where(
-            jnp.arange(n_dev, dtype=jnp.int32) == src_dev, 1e-3, 0.0
-        )
-        d = jnp.argmin(key)
-        ok = feasible[d]
-        start = starts_adj[d]
-        st, _ = _bisect(st, d, cfg_idx, tracks[d], slots[d], start,
-                        start + dur, do=ok, sanitize=sanitize)
-        return (st, n_ok + ok.astype(jnp.int32)), (ok, d, start)
-
-    (state, n_ok), (oks, devs, starts) = jax.lax.scan(
-        place_one, (state, jnp.asarray(0, jnp.int32)), None, length=n_tasks
-    )
-    all_ok = n_ok == n_tasks
-    if sanitize:
-        _sanitize.check_sched_state(state, "lp_place output")
-        _sanitize.check_no_avail_increase(
-            before,
-            _sanitize.total_availability(
-                state.win_t1, state.win_t2, state.win_valid
-            ),
-            "lp_place",
-        )
-    return all_ok, oks, devs, starts, state
-
-
-@functools.lru_cache(maxsize=None)
-def _lp_place_checked(cfg_idx: int, n_tasks: int):
-    fn = functools.partial(
-        lp_place_jit, cfg_idx=cfg_idx, n_tasks=n_tasks, sanitize=True
-    )
-    return checkify.checkify(fn, errors=checkify.user_checks)
-
-
-def lp_place(state: SchedState, src_dev, now, deadline, *,
-             cfg_idx: int = 1, n_tasks: int = 1):
-    """Public LP placement: dispatches to the checkify-sanitized variant
-    when ``REPRO_SANITIZE=1`` (repro.analysis.sanitize), raising
-    ``checkify.JaxRuntimeError`` on an invariant trip; otherwise runs the
-    check-free jitted program (``lp_place_jit``)."""
-    if _sanitize.enabled():
-        err, out = _lp_place_checked(cfg_idx, n_tasks)(
-            state, src_dev, now, deadline
-        )
-        err.throw()
-        return out
-    return lp_place_jit(
-        state, src_dev, now, deadline, cfg_idx=cfg_idx, n_tasks=n_tasks
-    )

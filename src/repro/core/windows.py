@@ -7,14 +7,10 @@ parallel tracks, each holding disjoint, sorted windows ``[t1, t2)`` of
 with early exit; allocation bisects the containing window; windows shorter
 than the list's minimum duration are discarded (they can never fit a task).
 
-Two implementations live here:
-
-- :class:`AvailabilityList` — the Python reference used by the simulator.
-  Mirrors the paper's C++ structure (linked variable-length windows).
-- :mod:`jax` functional form — fixed-capacity masked arrays
-  (``t1/t2/valid`` of shape ``[tracks, MAX_WINDOWS]``) so that the
-  multi-containment query of §IV.B.2 vmaps across every device in the
-  network in one XLA op.  See :func:`to_arrays`, :func:`find_slot_arrays`.
+:class:`AvailabilityList` is the serial reference.  It mirrors the
+paper's C++ structure (linked variable-length windows); the jitted
+statement of the same semantics is core/jax_state.py with the placement
+kernel's oracle (kernels/placement/ref.py).
 
 The abstraction's known accuracy loss (paper §VI.A): a window only records
 that *min_cores* are free, not total usage, so freed capacity cannot be
@@ -27,13 +23,7 @@ import dataclasses
 import math
 from typing import Optional, Sequence
 
-import numpy as np
-
 from repro.core.tasks import ALL_CONFIGS, DEVICE_CORES, Task, TaskConfig
-
-#: Fixed window capacity per track for the array/JAX form.  Overflowing
-#: windows are dropped, which is *sound* (scheduler becomes conservative).
-MAX_WINDOWS = 64
 
 
 @dataclasses.dataclass
@@ -158,21 +148,6 @@ class AvailabilityList:
                 return i
         return len(track)
 
-    # -- export -------------------------------------------------------------
-
-    def to_arrays(self, max_windows: int = MAX_WINDOWS) -> dict[str, np.ndarray]:
-        """Export to the fixed-capacity masked-array form used by the JAX
-        query path and the ``window_query`` Pallas kernel."""
-        t1 = np.full((self.track_count, max_windows), np.inf, dtype=np.float32)
-        t2 = np.full((self.track_count, max_windows), np.inf, dtype=np.float32)
-        valid = np.zeros((self.track_count, max_windows), dtype=bool)
-        for ti, track in enumerate(self.tracks):
-            for wi, w in enumerate(track[:max_windows]):
-                t1[ti, wi] = w.t1
-                t2[ti, wi] = min(w.t2, np.finfo(np.float32).max)
-                valid[ti, wi] = True
-        return {"t1": t1, "t2": t2, "valid": valid}
-
 
 class DeviceAvailability:
     """All availability lists of one device (one per configuration), plus the
@@ -231,46 +206,3 @@ class DeviceAvailability:
     def prune(self, now: float) -> None:
         """Drop completed work from the workload (bookkeeping only)."""
         self.workload = [t for t in self.workload if t.end_time is None or t.end_time > now]
-
-
-# ---------------------------------------------------------------------------
-# JAX functional form
-# ---------------------------------------------------------------------------
-
-import jax
-import jax.numpy as jnp
-
-
-def find_slot_arrays(t1, t2, valid, q1, deadline, dur):
-    """Vectorised containment query over one availability list.
-
-    Args:
-      t1, t2: ``[tracks, windows]`` float32 window bounds.
-      valid:  ``[tracks, windows]`` bool mask.
-      q1, deadline, dur: scalars.
-
-    Returns ``(found, flat_index, start)`` — the earliest feasible slot.
-    On SIMD hardware the paper's early-exit scan becomes a masked min-reduce:
-    one VPU pass instead of a data-dependent loop.
-    """
-    start = jnp.maximum(t1, q1)
-    feasible = valid & (start + dur <= jnp.minimum(t2, deadline))
-    key = jnp.where(feasible, start, jnp.inf)
-    flat = jnp.argmin(key.reshape(-1))
-    best = key.reshape(-1)[flat]
-    return best < jnp.inf, flat, best
-
-
-#: Multi-containment query of §IV.B.2: one list per device, queried for all
-#: devices in parallel.  Shapes: ``[devices, tracks, windows]``.
-multi_find_slot = jax.jit(
-    jax.vmap(find_slot_arrays, in_axes=(0, 0, 0, None, None, None))
-)
-
-
-def count_feasible(t1, t2, valid, q1, deadline, dur):
-    """How many distinct slots exist network-wide (used for the early-exit
-    'fewer windows than tasks' check in §IV.B.2)."""
-    start = jnp.maximum(t1, q1)
-    feasible = valid & (start + dur <= jnp.minimum(t2, deadline))
-    return feasible.sum()

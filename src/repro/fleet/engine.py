@@ -14,10 +14,9 @@ structures of core/jax_state.py — every LP placement attempt (the
 §IV.B.2 multi-containment query over both configs, device selection and
 the §IV.A.1 multi-remainder fan-out commit) runs through the *fused
 placement kernel* (kernels/placement/): one launch per attempt for the
-whole fleet, replacing the former window-query → argmin → vmapped-bisect
-chain.  Every ``compact_every`` ticks an in-scan compaction pass merges
-abutting windows per track so bisect remainders cannot clog the fixed-W
-slots.
+whole fleet.  Every ``compact_every`` ticks an in-scan compaction pass
+merges abutting windows per track so bisect remainders cannot clog the
+fixed-W slots.
 
 Long scans are *segmented*: `fleet_run` is a Python driver over a jitted
 ``segment_frames``-tick scan with donated carry buffers, so the XLA
@@ -224,6 +223,19 @@ def _vc_commit(vc, ok, sel, start, end, deadline, src):
     )
 
 
+def _avail_ahead(st: SchedState, base, p: FleetParams):
+    """Per-replica window time inside the span a tick's commits fall in:
+    from the tick's start to its last device's release plus the LP
+    deadline and the HP window.  The sanitized tick checks that it never
+    grows.  Clipped, because windows end at BIG, where an f32 total
+    cannot see a second."""
+    end = base + (1.0 + p.lp_deadline_factor) * FRAME_PERIOD + p.hp_deadline
+    clip = lambda x: jnp.clip(x, base, end)
+    return _sanitize.total_availability(
+        clip(st.win_t1), clip(st.win_t2), st.win_valid, batch_axes=1
+    )
+
+
 def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                   params: FleetParams, sanitize: bool = False):
     """One scan over a ``[S, B, Dev]`` trace segment.  ``f0`` is
@@ -275,6 +287,9 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
             ttime = (p.transfer_bytes * 8.0) / (
                 p.nominal_bw_bps * jnp.maximum(bws, 1e-3)
             )
+            if sanitize:
+                # availability the tick's commits start from, per replica
+                avail0 = _avail_ahead(st, base, p)
 
         # -- victim re-queue pass (§IV.B.3 reallocation) -------------------
         # Runs before this tick's frame releases so victims get first pick
@@ -521,6 +536,8 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     "negative link_free (fleet tick): {lf}",
                     lf=jnp.min(link_free),
                 )
+                _sanitize.check_no_avail_increase(
+                    avail0, _avail_ahead(st, base, p), "fleet tick")
             new = (st, link_free, (rq_dl, rq_src, rq_ok),
                    (vc_s, vc_end, vc_dl, vc_src, vc_ok), stats)
             # mask padded ticks (beyond the true trace) to exact no-ops so
@@ -757,7 +774,6 @@ def fleet_run(fleet: FleetState, values: jnp.ndarray, bw_scale: jnp.ndarray,
     sched, link_free, rq, vc, stats = carry
     out = FleetState(
         sched=sched, link_free=link_free,
-        now=jnp.full((B,), F * FRAME_PERIOD, jnp.float32),
         rq_deadline=rq[0], rq_src=rq[1], rq_valid=rq[2],
         vc_start=vc[0], vc_end=vc[1], vc_deadline=vc[2], vc_src=vc[3],
         vc_valid=vc[4],

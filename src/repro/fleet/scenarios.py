@@ -98,9 +98,21 @@ def _congestion_bursts(rng, F, B, duty: float) -> np.ndarray:
 
 
 def _draw_from_probs(rng, probs: dict[int, float], shape) -> np.ndarray:
-    vals = np.array(VALUES, np.int8)
+    """``rng.choice(VALUES, size=shape, p=probs)``, draw for draw.
+
+    ``choice`` maps each uniform to ``searchsorted(cdf, u, 'right')``, a
+    binary search per element; counting the CDF steps at or below ``u``
+    gives the same index in a few whole-array compares (about half the
+    time at [95, 4096, 4]) and leaves ``rng`` where ``choice`` does.
+    """
     p = np.array([probs[v] for v in VALUES], np.float64)
-    return rng.choice(vals, size=shape, p=p / p.sum())
+    cdf = np.cumsum(p / p.sum())
+    cdf /= cdf[-1]
+    u = rng.random(shape)
+    idx = np.zeros(shape, np.int8)
+    for c in cdf:
+        idx += u >= c
+    return np.array(VALUES, np.int8).take(idx)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Fleet state: B independent scheduler replicas as one pytree.
 
 `FleetState` stacks `SchedState` (core/jax_state.py) along a leading batch
-axis — every window/link array gains a `[B, ...]` dimension, so the whole
+axis — every window array gains a `[B, ...]` dimension, so the whole
 Monte-Carlo fleet is a valid `jax.lax.scan` carry and a single XLA
 program advances all replicas per tick.
 
@@ -14,9 +14,6 @@ Fleet-only fields ride along:
                         is the paper's §IV.A.2 discretisation collapsed to
                         its capacity-1 limit; per-replica bandwidth churn
                         (scenarios.py) scales each transfer's duration.
-    now        f32[B]   per-replica simulation clock (replicas share the
-                        frame grid but keep their own clock so partially
-                        filled batches stay independent).
 
 Preemption fidelity (§IV.B.3) needs two more groups of arrays:
 
@@ -55,7 +52,6 @@ from repro.core.tasks import ALL_CONFIGS, DEVICE_CORES
 class FleetState(NamedTuple):
     sched: SchedState        # every leaf carries a leading [B] axis
     link_free: jnp.ndarray   # [B]
-    now: jnp.ndarray         # [B]
     # victim re-queue buffer (preempted LP tasks awaiting re-placement)
     rq_deadline: jnp.ndarray  # f32[B, R]
     rq_src: jnp.ndarray       # i32[B, R]
@@ -97,7 +93,7 @@ def make_fleet(batch: int, n_devices: int = 4, bandwidth_bps: float = 20e6,
                *, max_windows: int = 16, requeue_slots: int = 4) -> FleetState:
     """A pristine B-replica fleet: every device fully available from t=0.
 
-    Built by exporting a fresh `RASScheduler` (so window/track/link layout
+    Built by exporting a fresh `RASScheduler` (so window/track layout
     is byte-identical to the reference path) and broadcasting it, in one
     device program: a sweep builds a fleet per batch, and each eager op
     would cost the host a dispatch while the device waits.
@@ -121,7 +117,6 @@ def _make_fleet(batch, n_devices, bandwidth_bps, max_windows, requeue_slots):
     return FleetState(
         sched=broadcast_state(base, batch),
         link_free=jnp.zeros((batch,), jnp.float32),
-        now=jnp.zeros((batch,), jnp.float32),
         rq_deadline=jnp.zeros((batch, requeue_slots), jnp.float32),
         rq_src=jnp.zeros((batch, requeue_slots), jnp.int32),
         rq_valid=jnp.zeros((batch, requeue_slots), bool),

@@ -25,7 +25,7 @@ from repro.analysis.pallas_check import (
 
 PRODUCTION_KERNELS = {
     "flash_attention", "flash_decode", "placement",
-    "ssd_scan", "ssm_scan", "window_query",
+    "ssd_scan", "ssm_scan",
 }
 
 

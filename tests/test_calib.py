@@ -115,5 +115,5 @@ def test_bench_registry_list_flag():
     )
     assert out.returncode == 0, out.stderr
     names = [line.split()[0] for line in out.stdout.strip().splitlines()]
-    for expected in ("completion", "fleet", "calib", "query", "roofline"):
+    for expected in ("completion", "fleet", "calib", "roofline"):
         assert expected in names

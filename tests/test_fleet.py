@@ -1,7 +1,5 @@
-"""Fleet subsystem tests: batched Pallas window-query equivalence (vs the
-unbatched kernel, the jnp oracle and the Python AvailabilityList
-reference, including the device-padding path), engine invariants,
-scenario registry and sweep plumbing.
+"""Fleet subsystem tests: engine invariants, scenario registry and sweep
+plumbing.
 
 All `fleet_run` invocations share one shape/params signature so the
 whole module pays for a single XLA compilation.
@@ -19,7 +17,6 @@ from _hyp import given, settings, st
 
 from repro.core.jax_state import export_state
 from repro.core.scheduler import RASScheduler
-from repro.core.tasks import LP2_CONFIG, LPRequest, Priority, Task
 from repro.fleet import (
     FleetParams,
     fleet_run,
@@ -27,106 +24,13 @@ from repro.fleet import (
     make_workload,
     run_sweep,
     scenario_names,
-    stack_states,
     summarize,
     SweepConfig,
-)
-from repro.kernels.window_query.ref import (
-    window_query_batched_ref,
-    window_query_ref,
-)
-from repro.kernels.window_query.window_query import (
-    window_query,
-    window_query_batched,
 )
 
 # One signature for every engine call in this module (single compile).
 B, F, DEV = 8, 8, 4
 PARAMS = FleetParams(n_devices=DEV)
-
-
-def _random_windows(b, dev, t, w, seed=0):
-    rng = np.random.default_rng(seed)
-    t1 = rng.uniform(0, 60, (b, dev, t, w)).astype(np.float32)
-    t2 = (t1 + rng.uniform(0, 40, (b, dev, t, w))).astype(np.float32)
-    valid = rng.random((b, dev, t, w)) < 0.7
-    return t1, t2, valid
-
-
-# ---------------------------------------------------------------------------
-# batched kernel equivalence
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dev,block_dev", [(4, 4), (6, 4), (5, 4), (3, 8)],
-                         ids=["exact", "pad2", "pad3", "clamp"])
-def test_batched_kernel_matches_unbatched(dev, block_dev):
-    """Each replica row of the batched kernel must equal the unbatched
-    kernel run on that replica — including when Dev is not divisible by
-    block_dev (padding path) and when block_dev > Dev (clamp path)."""
-    t1, t2, valid = _random_windows(5, dev, 2, 8, seed=dev)
-    q1, dl, dur = 10.0, 70.0, 6.0
-    fb, sb = window_query_batched(
-        t1, t2, valid, q1, dl, dur, block_dev=block_dev, interpret=True
-    )
-    for b in range(t1.shape[0]):
-        fu, su = window_query(
-            t1[b], t2[b], valid[b], q1, dl, dur,
-            block_dev=block_dev, interpret=True,
-        )
-        np.testing.assert_array_equal(np.asarray(fb[b]), np.asarray(fu))
-        np.testing.assert_allclose(np.asarray(sb[b]), np.asarray(su),
-                                   rtol=1e-6)
-
-
-def test_batched_kernel_matches_ref_per_replica_params():
-    """Per-(replica, device) q1/deadline/dur — the comm-adjusted offload
-    query — must match the jnp oracle."""
-    t1, t2, valid = _random_windows(6, 5, 2, 8, seed=9)
-    rng = np.random.default_rng(3)
-    q1 = rng.uniform(0, 30, (6, 5)).astype(np.float32)
-    dl = q1 + rng.uniform(20, 60, (6, 5)).astype(np.float32)
-    dur = rng.uniform(1, 10, (6, 5)).astype(np.float32)
-    fk, sk = window_query_batched(
-        t1, t2, valid, q1, dl, dur, block_dev=4, interpret=True
-    )
-    fr, sr = window_query_batched_ref(t1, t2, valid, q1, dl, dur)
-    np.testing.assert_array_equal(np.asarray(fk), np.asarray(fr))
-    np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-6)
-
-
-def _loaded_sched(seed, n_req=3):
-    s = RASScheduler(4, 20e6, seed=seed)
-    rng = np.random.default_rng(seed)
-    for i in range(n_req):
-        t = float(rng.uniform(0, 30))
-        req = LPRequest(
-            [Task(Priority.LOW, i % 4, t, t + 60.0, 0) for _ in range(2)],
-            i % 4, t,
-        )
-        s.schedule_lp(req, t)
-    return s
-
-
-@pytest.mark.parametrize("seeds", [(0, 3), (5, 9)])
-def test_batched_kernel_matches_python_availability(seeds):
-    """A stacked batch of live schedulers queried by the kernel must agree
-    with AvailabilityList.find_slot on every (replica, device)."""
-    scheds = [_loaded_sched(s) for s in seeds]
-    batch = stack_states([export_state(s) for s in scheds])
-    ci = 1  # lp2
-    q1, dl = 35.0, 95.0
-    dur = LP2_CONFIG.padded_time
-    fk, sk = window_query_batched(
-        batch.win_t1[:, :, ci], batch.win_t2[:, :, ci],
-        batch.win_valid[:, :, ci], q1, dl, dur,
-        block_dev=4, interpret=True,
-    )
-    for b, s in enumerate(scheds):
-        for d, dev in enumerate(s.devices):
-            py = dev.list_for(LP2_CONFIG).find_slot(q1, dl, dur)
-            assert bool(fk[b, d]) == (py is not None)
-            if py is not None:
-                assert abs(float(sk[b, d]) - py[2]) < 1e-3
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +269,7 @@ def test_make_fleet_broadcasts_the_exported_scheduler(batch, n_dev, slots):
         np.testing.assert_array_equal(
             np.asarray(got), np.broadcast_to(np.asarray(want),
                                              (batch,) + want.shape))
-    shapes = {"link_free": (batch,), "now": (batch,),
+    shapes = {"link_free": (batch,),
               "rq_deadline": (batch, slots), "rq_src": (batch, slots),
               "rq_valid": (batch, slots), "vc_start": (batch, n_dev),
               "vc_end": (batch, n_dev), "vc_deadline": (batch, n_dev),
@@ -449,6 +353,31 @@ def test_scenario_shapes_and_alphabet(name):
     assert wl.bw_scale.shape == (12, 6)
     assert wl.values.min() >= -1 and wl.values.max() <= 4
     assert (wl.bw_scale > 0).all() and (wl.bw_scale <= 1.2).all()
+
+
+@pytest.mark.parametrize("table", ["uniform", "weighted1", "weighted2",
+                                   "weighted3", "weighted4", "with_zeros"])
+def test_paper_trace_draw_is_rng_choice(table):
+    """The trace draw is ``rng.choice`` with ``p``, draw for draw, and
+    leaves the generator where ``choice`` leaves it."""
+    from repro.fleet.scenarios import _draw_from_probs
+    from repro.sim.traces import VALUES, _uniform_probs, _weighted_probs
+
+    if table == "uniform":
+        probs = _uniform_probs()
+    elif table == "with_zeros":
+        probs = dict(zip(VALUES, (0.0, 0.3, 0.0, 0.0, 0.7, 0.0)))
+    else:
+        probs = _weighted_probs(int(table[-1]))
+    p = np.array([probs[v] for v in VALUES], np.float64)
+    for seed, shape in ((0, (95, 64, 4)), (1, (3, 5, 50)), (2, (1, 1, 1))):
+        ours, theirs = np.random.default_rng(seed), np.random.default_rng(seed)
+        got = _draw_from_probs(ours, probs, shape)
+        want = theirs.choice(np.array(VALUES, np.int8), size=shape,
+                             p=p / p.sum())
+        assert got.dtype == want.dtype == np.int8
+        np.testing.assert_array_equal(got, want)
+        assert ours.random() == theirs.random()
 
 
 def test_scenario_reproducible_and_seed_sensitive():

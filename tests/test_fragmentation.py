@@ -334,13 +334,10 @@ def test_compaction_lowers_without_gather_sort_or_scatter():
     ``scatter`` primitive anywhere in its jaxpr."""
     shape = (8, 4, 3, 2, 16)
     win = jax.ShapeDtypeStruct(shape, jnp.float32)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     state = SchedState(
         win_t1=win, win_t2=win,
         win_valid=jax.ShapeDtypeStruct(shape, jnp.bool_),
-        min_dur=f32(8, 3), link_t1=f32(8, 4), link_t2=f32(8, 4),
-        link_cap=i32(8, 4), link_used=i32(8, 4),
+        min_dur=jax.ShapeDtypeStruct((8, 3), jnp.float32),
     )
 
     def prims(jaxpr):

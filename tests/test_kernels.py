@@ -5,14 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
 
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan
-from repro.kernels.window_query.ref import window_query_ref
-from repro.kernels.window_query.window_query import window_query
 
 RNG = np.random.default_rng(42)
 
@@ -108,61 +105,6 @@ def test_ssm_scan_state_carries_across_chunks():
     Cm = jnp.ones((B, S, N))
     out = ssm_scan(u, dt, A, Bm, Cm, block_d=32, chunk=32, interpret=True)
     assert float(jnp.abs(out[0, -1]).max()) > 1e-4  # leakage from t=0 visible
-
-
-# ---------------------------------------------------------------------------
-# window query
-# ---------------------------------------------------------------------------
-
-def _random_windows(dev, T, W):
-    t1 = RNG.uniform(0, 100, size=(dev, T, W)).astype(np.float32)
-    t2 = t1 + RNG.uniform(1, 50, size=(dev, T, W)).astype(np.float32)
-    valid = RNG.random((dev, T, W)) < 0.7
-    return jnp.asarray(t1), jnp.asarray(t2), jnp.asarray(valid)
-
-
-@pytest.mark.parametrize("dev,T,W", [(4, 2, 8), (64, 3, 16), (300, 2, 32)])
-def test_window_query_sweep(dev, T, W):
-    t1, t2, valid = _random_windows(dev, T, W)
-    found, start = window_query(t1, t2, valid, 10.0, 80.0, 17.2,
-                                block_dev=64, interpret=True)
-    f_ref, s_ref = window_query_ref(t1, t2, valid, 10.0, 80.0, 17.2)
-    np.testing.assert_array_equal(np.asarray(found), np.asarray(f_ref))
-    sel = np.asarray(f_ref, bool)
-    np.testing.assert_allclose(
-        np.asarray(start)[sel], np.asarray(s_ref)[sel], atol=1e-5
-    )
-
-
-@given(
-    q1=st.floats(0, 90), span=st.floats(5, 100), dur=st.floats(0.5, 40),
-    seed=st.integers(0, 2**16),
-)
-@settings(max_examples=25, deadline=None)
-def test_window_query_property_matches_python(q1, span, dur, seed):
-    """Kernel result == the paper's per-device Python containment query."""
-    rng = np.random.default_rng(seed)
-    dev, T, W = 8, 2, 8
-    t1 = rng.uniform(0, 100, size=(dev, T, W)).astype(np.float32)
-    t2 = t1 + rng.uniform(1, 60, size=(dev, T, W)).astype(np.float32)
-    valid = rng.random((dev, T, W)) < 0.8
-    deadline = q1 + span
-    found, start = window_query(
-        jnp.asarray(t1), jnp.asarray(t2), jnp.asarray(valid),
-        q1, deadline, dur, block_dev=8, interpret=True,
-    )
-    for d in range(dev):
-        best = None
-        for ti in range(T):
-            for wi in range(W):
-                if not valid[d, ti, wi]:
-                    continue
-                s = max(t1[d, ti, wi], q1)
-                if s + dur <= min(t2[d, ti, wi], deadline):
-                    best = s if best is None else min(best, s)
-        assert bool(found[d]) == (best is not None)
-        if best is not None:
-            assert abs(float(start[d]) - best) < 1e-4
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,14 @@
 
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hyp import given, settings, st
+from _sched import loaded_scheduler
 
+from repro.core.jax_state import export_state
 from repro.core.tasks import (
     ALL_CONFIGS,
     HP_CONFIG,
@@ -19,9 +23,10 @@ from repro.core.windows import (
     AvailabilityList,
     DeviceAvailability,
     Window,
-    find_slot_arrays,
-    multi_find_slot,
 )
+from repro.fleet.engine import HP_IDX, LP2_IDX, _hp_query
+from repro.fleet.state import broadcast_state
+from repro.kernels.placement.ops import fused_place_op
 
 
 def make_task(cfg, start, device=0, source=0):
@@ -140,35 +145,49 @@ class TestDeviceAvailability:
 
 
 class TestJaxParity:
-    def test_find_slot_arrays_matches_python(self):
-        rng = np.random.default_rng(0)
-        for _ in range(25):
-            al = AvailabilityList(LP2_CONFIG, horizon=(0.0, 500.0))
-            for _ in range(rng.integers(0, 6)):
-                s = float(rng.uniform(0, 400))
-                al.subtract(s, s + float(rng.uniform(1, 60)), 1)
-            arrs = al.to_arrays()
-            q1 = float(rng.uniform(0, 300))
-            deadline = q1 + float(rng.uniform(20, 200))
-            dur = al.min_duration
-            py = al.find_slot(q1, deadline, dur)
-            found, _, start = find_slot_arrays(
-                arrs["t1"], arrs["t2"], arrs["valid"], q1, deadline, dur
-            )
-            if py is None:
-                assert not bool(found)
-            else:
-                assert bool(found)
-                assert abs(float(start) - py[2]) < 1e-3
+    """The serial lists round-trip through ``export_state`` into the
+    jitted queries the fleet runs, which then agree with ``find_slot``
+    on every device."""
 
-    def test_multi_find_slot_vmaps_devices(self):
-        als = [AvailabilityList(LP2_CONFIG, horizon=(0.0, 500.0)) for _ in range(4)]
-        als[0].subtract(0.0, 500.0, 2)  # device 0 fully busy
-        arrs = [al.to_arrays() for al in als]
-        t1 = np.stack([a["t1"] for a in arrs])
-        t2 = np.stack([a["t2"] for a in arrs])
-        valid = np.stack([a["valid"] for a in arrs])
-        found, _, start = multi_find_slot(
-            t1, t2, valid, 0.0, 100.0, LP2_CONFIG.padded_time
-        )
-        assert not bool(found[0]) and all(bool(f) for f in found[1:])
+    def test_hp_query_matches_python_on_every_device(self):
+        for seed in (0, 7):
+            s = loaded_scheduler(seed, n_req=4)
+            st = broadcast_state(export_state(s), 1)
+            dur = HP_CONFIG.padded_time
+            for now in (2.0, 25.0, 40.0):
+                found, start = jax.vmap(
+                    lambda d: _hp_query(
+                        st, d, jnp.full((1,), now, jnp.float32),
+                        st.min_dur[:, HP_IDX], 0.0,
+                    )
+                )(jnp.arange(s.n_devices, dtype=jnp.int32))
+                for d, dev in enumerate(s.devices):
+                    py = dev.list_for(HP_CONFIG).find_slot(
+                        now, now + dur + 1e-6, dur)
+                    assert bool(found[d, 0]) == (py is not None), (now, d)
+                    if py is not None:
+                        assert abs(float(start[d, 0]) - py[2]) < 1e-3
+
+    def test_placement_query_matches_python_on_every_device(self):
+        for seed in (0, 7):
+            s = loaded_scheduler(seed, n_req=4)
+            n = s.n_devices
+            st = broadcast_state(export_state(s), n)
+            q1, dl = 35.0, 95.0
+            # replica d may only place on device d: every other device's
+            # deadline falls before its release
+            own = np.eye(n, dtype=bool)
+            _, _, _, ok, sel, start, *_ = fused_place_op(
+                st.win_t1, st.win_t2, st.win_valid, st.min_dur,
+                jnp.full((n, n), q1, jnp.float32),
+                jnp.asarray(np.where(own, dl, q1 - 1.0), jnp.float32),
+                jnp.arange(n, dtype=jnp.int32), jnp.ones((n,), bool),
+                backend="ref", cfg_pref=LP2_IDX, cfg_fallback=LP2_IDX,
+            )
+            for d, dev in enumerate(s.devices):
+                py = dev.list_for(LP2_CONFIG).find_slot(
+                    q1, dl, LP2_CONFIG.padded_time)
+                assert bool(ok[d]) == (py is not None), d
+                if py is not None:
+                    assert int(sel[d]) == d
+                    assert abs(float(start[d]) - py[2]) < 1e-3
