@@ -156,6 +156,24 @@ def _set_col(x, d, col):
                                                allow_negative_indices=False)
 
 
+def _pick(x, idx):
+    """``x[b, idx[b]]`` for a per-row index into the short minor axis of
+    ``x`` (the re-queue buffer's R slots): a chain of selects over its
+    static width, exact for every value, where a row gather would run
+    one index at a time on a TPU."""
+    out = x[:, 0]
+    for r in range(1, x.shape[1]):
+        out = jnp.where(idx == r, x[:, r], out)
+    return out
+
+
+def _put(x, idx, val):
+    """``x.at[b, idx[b]].set(val[b])`` per row, as one select; an index
+    outside ``[0, R)`` writes nothing."""
+    hit = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :] == idx[:, None]
+    return jnp.where(hit, val[:, None], x)
+
+
 def _hp_query(st: SchedState, dev, now, dur, hp_deadline: float):
     """HP containment query on device ``dev`` (traced): a `dur` slot
     starting in [now, now + hp_deadline - dur] (§IV.B.1)."""
@@ -255,7 +273,6 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
         [d * (FRAME_PERIOD / n_dev) * p.stagger for d in range(n_dev)],
         jnp.float32)
     dl_offset = t_offset + p.lp_deadline_factor * FRAME_PERIOD
-    rows = jnp.arange(B, dtype=jnp.int32)
     if sanitize:
         _sanitize.check_sched_state(carry[0], "fleet segment input")
 
@@ -313,9 +330,9 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                 # one attempt per tick drains the buffer in practice while
                 # costing a single fused-kernel launch)
                 slot = jnp.argmin(jnp.where(rq_ok, rq_dl, BIG), axis=1)
-                valid_r = rq_ok[rows, slot]
-                dl = rq_dl[rows, slot]
-                src = rq_src[rows, slot]
+                valid_r = _pick(rq_ok, slot)
+                dl = _pick(rq_dl, slot)
+                src = _pick(rq_src, slot)
                 comm_end = jnp.maximum(link_free, now0) + ttime
                 q1 = jnp.where(
                     dev_ids[None, :] == src[:, None], now0[:, None],
@@ -340,7 +357,7 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     comm_busy=stats.comm_busy + jnp.where(offl, ttime, 0.0),
                     remainders_dropped=stats.remainders_dropped + nd,
                 )
-                rq_ok = rq_ok.at[rows, slot].set(valid_r & ~ok)
+                rq_ok = _put(rq_ok, slot, valid_r & ~ok)
 
         def device_step(d, dc):
             # one device's frame release; ``d`` is traced, so every device
@@ -444,14 +461,12 @@ def _segment_impl(carry, values, bw_scale, f0, n_frames, *,
                     has_free = ~rq_ok.all(axis=1)
                     unplaced = preempt & ~ok_v
                     push = unplaced & has_free
-                    rq_dl = rq_dl.at[rows, free].set(
-                        jnp.where(push, dl_v, rq_dl[rows, free])
-                    )
-                    rq_src = rq_src.at[rows, free].set(
-                        jnp.where(push, src_v, rq_src[rows, free])
-                    )
-                    rq_ok = rq_ok.at[rows, free].set(
-                        rq_ok[rows, free] | push)
+                    # slot R does not exist: a replica that pushes
+                    # nothing writes nothing
+                    at = jnp.where(push, free, R)
+                    rq_dl = _put(rq_dl, at, dl_v)
+                    rq_src = _put(rq_src, at, src_v)
+                    rq_ok = _put(rq_ok, at, push)
                     stats = stats._replace(
                         missed_by_preemption=stats.missed_by_preemption
                         + (unplaced & ~has_free),

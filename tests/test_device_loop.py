@@ -10,7 +10,10 @@ does not depend on the device count.
 - the lowered segment program holds as many placement call sites at 50
   devices as at 4;
 - the HP commit trims the device's row on a dynamic slice and writes it
-  back in place, bit-identical to the gather/scatter ``fanout_commit``.
+  back in place, bit-identical to the gather/scatter ``fanout_commit``;
+- the re-queue buffer is read and written at a per-row slot by selects
+  over its R slots, bit-identical to the row gather and scatter, so the
+  segment program holds no gather or scatter at all.
 """
 
 import os
@@ -183,3 +186,70 @@ def test_hp_commit_lowers_without_gather_or_scatter():
         jnp.full((n,), 1, jnp.int32), jnp.zeros((n,), jnp.int32),
         s, e, do).jaxpr)
     assert {"gather", "scatter"} <= old
+
+
+def _requeue_case(dtype, R: int, pattern: str, n: int = 16, seed: int = 3):
+    """A ``[n, R]`` re-queue buffer of ``dtype``, one slot index per row
+    and one value per row.  The f32 buffer holds -0.0, ``BIG`` and
+    negative values, where a pick that sums masked zeros would differ."""
+    rng = np.random.default_rng(seed + R)
+    if dtype == np.float32:
+        pool = np.array([-0.0, 0.0, engine.BIG, -engine.BIG, -3.5, -1e-30,
+                         7.25, np.inf, -np.inf], np.float32)
+        x, val = rng.choice(pool, (n, R)), rng.choice(pool, n)
+    elif dtype == np.int32:
+        lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+        x = rng.integers(lo, hi, (n, R), dtype=np.int32, endpoint=True)
+        val = rng.integers(lo, hi, n, dtype=np.int32, endpoint=True)
+    else:
+        x, val = rng.random((n, R)) < 0.5, rng.random(n) < 0.5
+    x, val = x.astype(dtype), val.astype(dtype)
+    # the slots the engine indexes: the first free one (all free, all
+    # full, mixed), slot 0 everywhere, or any slot
+    ok = {"all_free": np.zeros((n, R), bool), "all_full": np.ones((n, R), bool),
+          "mixed": rng.random((n, R)) < 0.5}.get(pattern)
+    if ok is not None:
+        idx = np.argmin(ok, axis=1)
+    elif pattern == "zero":
+        idx = np.zeros(n, np.int64)
+    else:
+        idx = rng.integers(0, R, n)
+    return jnp.asarray(x), jnp.asarray(idx, jnp.int32), jnp.asarray(val)
+
+
+@pytest.mark.parametrize("pattern",
+                         ["all_free", "all_full", "mixed", "zero", "any"])
+@pytest.mark.parametrize("R", [1, 4, 7])
+@pytest.mark.parametrize("dtype", [np.float32, np.int32, np.bool_],
+                         ids=["f32", "i32", "bool"])
+def test_requeue_select_matches_indexed_form_bit_for_bit(dtype, R, pattern):
+    x, idx, val = _requeue_case(dtype, R, pattern)
+    rows = jnp.arange(x.shape[0])
+    bits = lambda a: np.asarray(a).view(np.uint8)
+    got = jax.jit(engine._pick)(x, idx)
+    np.testing.assert_array_equal(bits(got), bits(x[rows, idx]))
+    got = jax.jit(engine._put)(x, idx, val)
+    np.testing.assert_array_equal(bits(got), bits(x.at[rows, idx].set(val)))
+    # the engine's "no slot" index R writes nothing
+    skip = jnp.where(jnp.arange(x.shape[0]) % 2 == 0, idx, R)
+    got = jax.jit(engine._put)(x, skip, val)
+    want = x.at[rows, skip].set(val, mode="drop")
+    np.testing.assert_array_equal(bits(got), bits(want))
+
+
+@pytest.mark.parametrize("n_dev", [4, 50])
+def test_segment_lowers_without_gather_or_scatter(n_dev):
+    """Every read and write of the re-queue buffers is a select over its
+    R slots, and the HP commit a dynamic slice, so no phase of the tick
+    indexes rows one at a time."""
+    batch = 8
+    params = FleetParams(n_devices=n_dev, placement_backend="ref")
+    wl = make_workload("weighted4", batch, 4, n_dev, seed=1, congestion=0.3)
+    args = (engine.initial_carry(make_fleet(batch, n_dev)),
+            jnp.asarray(wl.values, jnp.int32),
+            jnp.asarray(wl.bw_scale, jnp.float32),
+            jnp.int32(0), jnp.int32(4))
+    seg = lambda *a: engine._segment_impl(*a, params=params)
+    prims = _primitives(jax.make_jaxpr(seg)(*args).jaxpr)
+    assert "scan" in prims
+    assert not {p for p in prims if "gather" in p or "scatter" in p}, prims

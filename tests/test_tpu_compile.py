@@ -139,6 +139,19 @@ def test_hp_commit_has_no_window_scatter_or_flat_relayout_on_v5e(
                 if " copy(" in ln and flat in ln.split(" copy(")[0]]
 
 
+@pytest.mark.parametrize("B, n_dev", [(4096, DEV), (128, 50)],
+                         ids=["paper_site", "site50"])
+def test_requeue_has_no_gather_or_scatter_on_v5e(segment_hlo, B, n_dev):
+    """The re-queue buffers (``[B, R]``, R = 4) are read and written by
+    selects over R, so the compiled segment holds no gather or scatter.
+    A per-row index into them lowers on a v5e to gathers of ``[B]`` and
+    scatters into ``[B, R]`` or, at B=4096, into a flattened ``[B x R]``
+    view that carries no op name: hence no shape or scope filter."""
+    lines = [ln.strip()[:160] for ln in segment_hlo(B, n_dev).splitlines()
+             if " gather(" in ln or " scatter(" in ln]
+    assert not lines, lines
+
+
 def test_placement_scopes_on_v5e(one_chip):
     """Under a profiler the launch is charged to ``placement/kernel`` and
     the boundary transposes to ``placement/layout``."""
